@@ -400,6 +400,8 @@ def main() -> None:
                          "run (CI's engine-mesh screen-smoke step runs it "
                          "on the forced-8-device mesh)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import fl_benchmarks as flb
 

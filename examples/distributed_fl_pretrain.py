@@ -31,6 +31,8 @@ def main():
 
     os.environ["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={args.devices}")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -43,10 +45,11 @@ def main():
     from repro.data.tokens import TokenDataConfig, make_batches
     from repro.models import layers as Lyr
     from repro.models.base import get_family
+    from repro.launch.mesh import auto_mesh
     from repro.launch.shardings import batch_spec, leaf_spec, tree_shardings
 
     G = args.data_axis
-    mesh = jax.make_mesh((G, args.devices // G), ("data", "model"))
+    mesh = auto_mesh((G, args.devices // G), ("data", "model"))
     cfg = get_config("smollm-360m").replace(
         n_layers=args.layers, d_model=args.d_model, n_heads=4, n_kv_heads=2,
         d_head=args.d_model // 4, d_ff=2 * args.d_model, vocab=2048,

@@ -37,6 +37,8 @@ def main():
     ap.add_argument("--window", type=float, default=0.0,
                     help="cohort staleness window in virtual seconds")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.engine import EngineConfig
     engine = EngineConfig(

@@ -33,6 +33,8 @@ def main():
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count="
             f"{args.devices}").strip()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
 
     from repro.core.testbed import TestbedConfig, build_testbed, run_experiment

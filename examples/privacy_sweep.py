@@ -88,6 +88,8 @@ def main():
                          "through one Session")
     ap.add_argument("--max-updates", type=int, default=120)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     analytic()
     if args.train:
         trained(args.max_updates)

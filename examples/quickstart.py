@@ -13,6 +13,8 @@ from repro.data.synthetic_ser import SERDataConfig
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = TestbedConfig(use_dp=True, sigma=1.0, batch_size=64,
                         data=SERDataConfig(n_total=1600), seed=0)
 

@@ -5,12 +5,9 @@
 # - tcmalloc, when present, replaces glibc malloc (host-side arena
 #   assembly and numpy batch planning allocate heavily);
 # - TF_CPP_MIN_LOG_LEVEL=4 silences the TF/XLA dataset warning spam;
-# - --xla_step_marker_location=1 puts the step marker on the outer while
-#   loop (0 = program entry) so profiles attribute whole cohort steps —
-#   TPU-only flag (CPU/GPU XLA builds abort on unknown flags), added when
-#   a TPU is detected or REPRO_TPU=1 forces it;
 # - REPRO_HOST_DEVICES=N forces N host platform devices (the forced-mesh
-#   CI/bench topology; unset = real device count);
+#   CI/bench topology; unset = real device count) — refused on a TPU
+#   host, where the program must run on the chips;
 # - REPRO_PALLAS_INTERPRET=0/1 overrides the Pallas interpret-mode policy
 #   (see src/repro/kernels/common.py) — exported through untouched.
 set -euo pipefail
@@ -22,14 +19,13 @@ if [[ -z "${LD_PRELOAD:-}" && -e "$TCMALLOC" ]]; then
 fi
 export TF_CPP_MIN_LOG_LEVEL=4                   # no dataset warnings
 
-XLA_FLAGS="${XLA_FLAGS:-}"
-if [[ -n "${REPRO_TPU:-}" || -e /dev/accel0 || -c /dev/vfio/0 ]]; then
-  XLA_FLAGS="--xla_step_marker_location=1 ${XLA_FLAGS}"  # 0 = entry; 1 = outer while
-fi
 if [[ -n "${REPRO_HOST_DEVICES:-}" ]]; then
-  XLA_FLAGS="--xla_force_host_platform_device_count=${REPRO_HOST_DEVICES} ${XLA_FLAGS}"
+  if [[ -e /dev/accel0 || -c /dev/vfio/0 ]]; then
+    echo "launch.sh: REPRO_HOST_DEVICES forces CPU devices; unset it on a TPU host" >&2
+    exit 2
+  fi
+  export XLA_FLAGS="--xla_force_host_platform_device_count=${REPRO_HOST_DEVICES} ${XLA_FLAGS:-}"
 fi
-export XLA_FLAGS
 
 REPO_ROOT="$(cd "$(dirname "${BASH_SOURCE[0]}")/.." && pwd)"
 export PYTHONPATH="${REPO_ROOT}/src${PYTHONPATH:+:${PYTHONPATH}}"

@@ -72,7 +72,10 @@ sweep invariant and float-tolerance parity with ``dp_path="jnp"`` and
 the legacy loop (asserted by tests/test_dp_path_engine.py).  Padded
 mask members ride along exactly like every other masked step: their
 kernel row is computed and discarded (``n_steps=0`` masks the update,
-the merge gives them coefficient 0).
+the merge gives them coefficient 0).  On a mesh the kernel runs under
+``shard_map`` over the cohort axis, each device launching it on its own
+members: GSPMD cannot partition a Mosaic kernel, and a cohort that would
+stay replicated is refused.
 """
 from __future__ import annotations
 
@@ -82,6 +85,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.dp import (
     DPConfig, dp_mean_gradient, per_example_grads, validate_dp_path)
@@ -423,6 +427,39 @@ def make_cohort_step(loss_fn: Callable, dp_cfg: DPConfig, opt,
         from repro.kernels.dp_clip.ops import dp_clip_mean_noise_cohort
         from repro.pytree import tree_gaussian_vector_like
 
+        if client_shardings is not None and not callable(client_shardings):
+            raise ValueError(
+                "dp_path='pallas' on a mesh needs a shape-aware callable "
+                "shardings rule (engine.mesh_backend.CohortSharding): the "
+                "kernel is partitioned per member along the cohort axis")
+
+        def clip_mean_noise(flat, *noise):
+            """(K, B, D) per-example grads [+ the runtime stddev and (K, D)
+            draws] -> (K, D) clipped (noised) member means."""
+            return dp_clip_mean_noise_cohort(flat, dp_cfg.clip_norm, *noise)[0]
+
+        def kernel_members(flat, *noise):
+            """The kernel over the cohort, partitioned per member on a
+            mesh: a Mosaic kernel cannot be partitioned by GSPMD, so it
+            runs under ``shard_map`` with each device taking its own
+            members (the kernel grid is already per member).  A cohort
+            whose rule replicates it is refused rather than run
+            replicated on every device."""
+            sh = None if client_shardings is None else client_shardings(flat)
+            if sh is None or sh.mesh.size == 1:
+                return clip_mean_noise(flat, *noise)
+            lead = sh.spec[0] if len(sh.spec) else None
+            if lead is None:
+                raise ValueError(
+                    f"dp_path='pallas': a cohort of {flat.shape[0]} members "
+                    f"does not partition over the mesh {dict(sh.mesh.shape)};"
+                    " use the device-arena data path, which pads cohorts "
+                    "to a multiple of the data axes")
+            specs = (P(lead), P(), P(lead))[:1 + len(noise)]
+            return jax.shard_map(
+                clip_mean_noise, mesh=sh.mesh, in_specs=specs,
+                out_specs=P(lead), check_vma=False)(flat, *noise)
+
         def fused_one_step(stacked_params, stacked_opt, ks, batch_s,
                            step_i, n_steps, noise_stddev):
             """All K members' DP-SGD step s, ONE fused kernel launch over
@@ -442,13 +479,11 @@ def make_cohort_step(loss_fn: Callable, dp_cfg: DPConfig, opt,
                 [l.reshape(K, bsz, -1).astype(jnp.float32) for l in leaves],
                 axis=2)                                   # (K, B, D)
             template = jax.tree_util.tree_map(lambda l: l[0, 0], g_per)
+            noise = ()
             if add_noise:
-                z = jax.vmap(
-                    lambda k: tree_gaussian_vector_like(k, template))(subs)
-                means, _, _ = dp_clip_mean_noise_cohort(
-                    flat, dp_cfg.clip_norm, noise_stddev, z)
-            else:
-                means, _, _ = dp_clip_mean_noise_cohort(flat, dp_cfg.clip_norm)
+                noise = (noise_stddev, jax.vmap(
+                    lambda k: tree_gaussian_vector_like(k, template))(subs))
+            means = kernel_members(flat, *noise)
             grads = _unflatten_members(means, template)
             p_new, o_new = jax.vmap(opt.update)(
                 grads, stacked_opt, stacked_params)

@@ -38,15 +38,15 @@ def dp_clip_mean_noise_cohort(g, clip_norm: float, noise_stddev=None, z=None):
     """
     K, B, D = g.shape
     interp = interpret_mode()
-    tb, td = min(DEFAULT_TB, B), min(DEFAULT_TD, D)
+    # row tile: a multiple of 8 (the sublane tile), at most DEFAULT_TB
+    tb, td = min(DEFAULT_TB, -(-B // 8) * 8), min(DEFAULT_TD, D)
     gp = _pad_to(_pad_to(g, tb, 1), td, 2)          # (K, Bp, Dp)
     Bp, Dp = gp.shape[1], gp.shape[2]
     flat = gp.reshape(K * Bp, Dp)
-    sq = sqnorms(flat, tb=tb, td=td, interpret=interp)
-    norms = jnp.sqrt(sq)                            # (K*Bp,)
+    norms = jnp.sqrt(sqnorms(flat, tb=tb, td=td, interpret=interp))  # (K*Bp, 1)
     scales = 1.0 / jnp.maximum(1.0, norms / clip_norm)
     if z is not None:
-        z = _pad_to(z.astype(jnp.float32), td, 1)   # (K, Dp)
+        z = _pad_to(z.astype(jnp.float32), td, 1).reshape(K, 1, Dp)
         stddev = jnp.asarray(noise_stddev, jnp.float32).reshape(1, 1)
     else:
         stddev = None
@@ -54,7 +54,7 @@ def dp_clip_mean_noise_cohort(g, clip_norm: float, noise_stddev=None, z=None):
                               z=z, stddev=stddev,
                               tb=tb, td=td, interpret=interp)
     norms = norms.reshape(K, Bp)[:, :B]
-    return (means[:, :D], jnp.mean(norms, axis=1),
+    return (means.reshape(K, Dp)[:, :D], jnp.mean(norms, axis=1),
             jnp.mean((norms > clip_norm).astype(jnp.float32), axis=1))
 
 

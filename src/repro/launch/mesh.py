@@ -8,13 +8,26 @@ import; smoke tests and benchmarks see the real single CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    ``make_mesh`` builds ``Explicit`` axes by default, and
+    ``with_sharding_constraint`` — how the cohort step and the SPMD
+    layers place their leaves — accepts only specs over ``Auto`` axes.
+    Every mesh in this repo comes from here."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (2 pods, 512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def largest_divisor(n: int, k: int) -> int:
@@ -40,8 +53,8 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = largest_divisor(n, data)
     model = largest_divisor(n // data, model)
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[: data * model])
+    return auto_mesh((data, model), ("data", "model"),
+                     devices=jax.devices()[: data * model])
 
 
 def data_axes(mesh) -> tuple:

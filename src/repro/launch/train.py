@@ -70,6 +70,7 @@ def run_spmd(args):
     from repro.data.tokens import TokenDataConfig, make_batches
     from repro.models import layers as Lyr
     from repro.models.base import get_family
+    from repro.launch.mesh import auto_mesh
     from repro.launch.shardings import batch_spec, leaf_spec, tree_shardings
 
     cfg = get_config(args.arch)
@@ -82,7 +83,7 @@ def run_spmd(args):
     fam = get_family(cfg.family)
 
     G = args.data_axis
-    mesh = jax.make_mesh((G, args.devices // G), ("data", "model"))
+    mesh = auto_mesh((G, args.devices // G), ("data", "model"))
     Lyr.set_mesh_context(mesh, None, "model")  # no batch constraints (§Perf)
 
     fl = FLStepConfig(

@@ -319,7 +319,8 @@ def test_seeded_replicated_client_axis_fails(compiled_step):
 def test_seeded_forced_all_gather_fails():
     from jax.sharding import NamedSharding, PartitionSpec as P
     n = len(jax.devices())
-    mesh = jax.make_mesh((n,), ("data",))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((n,), ("data",))
     sharded = NamedSharding(mesh, P("data"))
     repl = NamedSharding(mesh, P())
     f = jax.jit(lambda v: v + 1.0, in_shardings=(sharded,),

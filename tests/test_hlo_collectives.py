@@ -16,8 +16,9 @@ sys.path.insert(0, "src"); sys.path.insert(0, ".")
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from benchmarks.hlo_analysis import analyze
+from repro.launch.mesh import auto_mesh
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = auto_mesh((8,), ("x",))
 sh = NamedSharding(mesh, P("x"))
 repl = NamedSharding(mesh, P())
 

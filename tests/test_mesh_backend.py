@@ -28,12 +28,8 @@ multi_device = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def amesh():
-    # AbstractMesh: shape metadata without devices (ctor changed across
-    # jax releases — see tests/test_shardings.py)
-    try:
-        return jax.sharding.AbstractMesh((8, 1), ("data", "model"))
-    except TypeError:
-        return jax.sharding.AbstractMesh((("data", 8), ("model", 1)))
+    # AbstractMesh: shape metadata without devices
+    return jax.sharding.AbstractMesh((8, 1), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------

@@ -12,13 +12,8 @@ from repro.configs import get_config
 
 @pytest.fixture(scope="module")
 def mesh():
-    # AbstractMesh: shape metadata without devices.  The constructor
-    # signature changed across jax releases: >=0.5 takes (sizes, names),
-    # 0.4.x takes a tuple of (name, size) pairs.
-    try:
-        return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:
-        return jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
+    # AbstractMesh: shape metadata without devices
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def _spec(shape, cfg, mesh, role="master"):
