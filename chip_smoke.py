@@ -42,7 +42,12 @@ import sys
 import time
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
+ROOT = Path(__file__).resolve().parent
+for _p in (str(ROOT), str(ROOT / "src")):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+from bench.checks import CompileClock  # noqa: E402
 
 UPDATES = 40            # FedAsync budget: merged updates per run
 EVAL_EVERY = 10
@@ -84,31 +89,6 @@ def device_check(chips):
         sys.exit(f"chip_smoke: needs {chips} TPU chips, found {len(devs)}")
     return {"platform": d0.platform, "kind": d0.device_kind,
             "count": len(devs)}
-
-
-class CompileClock:
-    """Seconds the XLA backend spent compiling, and how many programs the
-    persistent compilation cache served instead, from JAX's own
-    monitoring events (tracing and lowering are not counted: their
-    events nest and would count twice)."""
-
-    COMPILE = "/jax/core/compile/backend_compile_duration"
-    CACHE_HIT = "/jax/compilation_cache/cache_hits"
-
-    def __init__(self):
-        from jax import monitoring
-        self.seconds = 0.0
-        self.cache_hits = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if event == self.COMPILE:
-            self.seconds += duration
-
-    def _on_event(self, event, **_):
-        if event == self.CACHE_HIT:
-            self.cache_hits += 1
 
 
 # ---------------------------------------------------------------------------

@@ -537,12 +537,93 @@ class PerClientLoopInStoreRegion(Rule):
         return False
 
 
+# ---------------------------------------------------------------------------
+# REP007 — a profiler span outside the span registry
+# ---------------------------------------------------------------------------
+
+_ANNOTATIONS = ("TraceAnnotation", "StepTraceAnnotation")
+_SPAN_OPENERS = ("span", "step_span")
+_REGISTRY = "SPAN_NAMES"
+_SPAN_PREFIX = "fl."
+
+
+def _call_name(node) -> str:
+    f = node.func
+    if isinstance(f, ast.Attribute):
+        return f.attr
+    if isinstance(f, ast.Name):
+        return f.id
+    return ""
+
+
+def _registered_names(tree):
+    """The string literals of a module-level ``SPAN_NAMES = (...)``, or
+    None when the module defines no registry."""
+    for node in tree.body:
+        if (isinstance(node, ast.Assign) and len(node.targets) == 1
+                and isinstance(node.targets[0], ast.Name)
+                and node.targets[0].id == _REGISTRY
+                and isinstance(node.value, (ast.Tuple, ast.List))):
+            return {e.value for e in node.value.elts
+                    if isinstance(e, ast.Constant)
+                    and isinstance(e.value, str)}
+    return None
+
+
+class SpanOutsideRegistry(Rule):
+    """Host spans go through ``repro.core.spans`` with a registered name.
+
+    History: the chip benchmark puts every device idle gap down to the
+    innermost host span open in it, and its readers find spans by name.
+    A ``TraceAnnotation`` opened directly, or a span whose name is not
+    in ``SPAN_NAMES``, is a span no reader knows: its time silently
+    moves out of the phase that owns it.  The rule flags
+    ``TraceAnnotation``/``StepTraceAnnotation`` calls outside the module
+    that defines ``SPAN_NAMES``, and ``span(...)``/``step_span(...)``
+    calls whose name is not a string literal starting ``fl.`` and, when
+    the registry is among the linted files, listed in it.
+    """
+
+    code = "REP007"
+    title = "profiler span outside the span registry"
+
+    def check(self, file, ctx):
+        is_registry = _registered_names(file.tree) is not None
+        registry = set()
+        for f in ctx.files:
+            registry |= _registered_names(f.tree) or set()
+        findings = []
+        for node in ast.walk(file.tree):
+            if not isinstance(node, ast.Call):
+                continue
+            name = _call_name(node)
+            if name in _ANNOTATIONS and not is_registry:
+                findings.append(self.finding(
+                    file, node,
+                    f"`{name}` opened directly — open host spans with "
+                    "repro.core.spans.span/step_span and a name from "
+                    "SPAN_NAMES"))
+            elif name in _SPAN_OPENERS and node.args:
+                arg = node.args[0]
+                literal = (isinstance(arg, ast.Constant)
+                           and isinstance(arg.value, str))
+                if (not literal or not arg.value.startswith(_SPAN_PREFIX)
+                        or (registry and arg.value not in registry)):
+                    findings.append(self.finding(
+                        file, node,
+                        f"`{name}` with a name that is not a literal "
+                        "from SPAN_NAMES — add the name to "
+                        "repro.core.spans.SPAN_NAMES"))
+        return findings
+
+
 RULES = {
     r.code: r for r in (
         CacheKeyCompleteness(), SpecCodecCompleteness(), StaticDivisor(),
-        DonatedReuse(), HostSyncInHotRegion(), PerClientLoopInStoreRegion())
+        DonatedReuse(), HostSyncInHotRegion(), PerClientLoopInStoreRegion(),
+        SpanOutsideRegistry())
 }
 
 __all__ = ["RULES", "Rule", "CacheKeyCompleteness", "SpecCodecCompleteness",
            "StaticDivisor", "DonatedReuse", "HostSyncInHotRegion",
-           "PerClientLoopInStoreRegion"]
+           "PerClientLoopInStoreRegion", "SpanOutsideRegistry"]

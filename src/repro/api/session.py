@@ -46,6 +46,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from repro.api.spec import ExperimentSpec, StrategySpec, replace_path
+from repro.core import spans
 from repro.core.testbed import (
     TestbedConfig, build_clients, build_partitions, partition_key)
 
@@ -143,52 +144,55 @@ class Session:
     def _materialize(self, tb: TestbedConfig):
         """Clients + initial params + eval closures for ``tb``, reusing
         cached partitions / the live testbed where the config allows."""
-        if self._testbed_cfg == tb:
-            for c in self._clients:
-                c.reset()
-            self.events["testbed_reuses"] += 1
-            return
-        pk = partition_key(tb)
-        cached = self._partitions.get(pk)
-        if cached is None:
-            cached = build_partitions(tb)
-            self._partitions[pk] = cached
-            self.events["partition_builds"] += 1
-        else:
-            self.events["partition_reuses"] += 1
-        splits, pooled = cached
-        from repro.api.workloads import get_workload
-        import jax
-        wl = get_workload(tb.workload)
-        self._clients = build_clients(tb, splits)
-        self._params0 = wl.init(jax.random.PRNGKey(tb.seed), tb.model)
-        self._acc_fn = wl.shared_accuracy(tb.model)
-        self._pooled = pooled
-        self._testbed_cfg = tb
-        self._runner = None            # built over the OLD clients
-        self._runner_key = None
-        self.events["testbed_builds"] += 1
+        with spans.span("fl.session.testbed"):
+            if self._testbed_cfg == tb:
+                for c in self._clients:
+                    c.reset()
+                self.events["testbed_reuses"] += 1
+                return
+            pk = partition_key(tb)
+            cached = self._partitions.get(pk)
+            if cached is None:
+                with spans.span("fl.session.partitions"):
+                    cached = build_partitions(tb)
+                self._partitions[pk] = cached
+                self.events["partition_builds"] += 1
+            else:
+                self.events["partition_reuses"] += 1
+            splits, pooled = cached
+            from repro.api.workloads import get_workload
+            import jax
+            wl = get_workload(tb.workload)
+            self._clients = build_clients(tb, splits)
+            self._params0 = wl.init(jax.random.PRNGKey(tb.seed), tb.model)
+            self._acc_fn = wl.shared_accuracy(tb.model)
+            self._pooled = pooled
+            self._testbed_cfg = tb
+            self._runner = None            # built over the OLD clients
+            self._runner_key = None
+            self.events["testbed_builds"] += 1
 
     def _get_runner(self, tb: TestbedConfig, engine_cfg):
-        from repro.engine import CohortRunner
-        key = (tb, engine_cfg)
-        if self._runner_key == key:
-            self._runner.reset_for_run()
-            self.events["runner_reuses"] += 1
-        else:
-            arena_key = (partition_key(tb), engine_cfg.mesh)
-            arena = self._data_arenas.get(arena_key)
-            self._runner = CohortRunner(self._clients, engine_cfg,
-                                        data_arena=arena)
-            if getattr(self._runner, "use_arena", False):
-                if arena is None:
-                    self._data_arenas[arena_key] = self._runner.data_arena
-                    self.events["data_arena_builds"] += 1
-                else:
-                    self.events["data_arena_reuses"] += 1
-            self._runner_key = key
-            self.events["runner_builds"] += 1
-        return self._runner
+        with spans.span("fl.session.runner"):
+            from repro.engine import CohortRunner
+            key = (tb, engine_cfg)
+            if self._runner_key == key:
+                self._runner.reset_for_run()
+                self.events["runner_reuses"] += 1
+            else:
+                arena_key = (partition_key(tb), engine_cfg.mesh)
+                arena = self._data_arenas.get(arena_key)
+                self._runner = CohortRunner(self._clients, engine_cfg,
+                                            data_arena=arena)
+                if getattr(self._runner, "use_arena", False):
+                    if arena is None:
+                        self._data_arenas[arena_key] = self._runner.data_arena
+                        self.events["data_arena_builds"] += 1
+                    else:
+                        self.events["data_arena_reuses"] += 1
+                self._runner_key = key
+                self.events["runner_builds"] += 1
+            return self._runner
 
     # -- execution ---------------------------------------------------------
     def run(self, spec: ExperimentSpec, *,
@@ -210,61 +214,66 @@ class Session:
         raises :class:`repro.engine.resilience.SimulatedCrash` after that
         many snapshots (deterministic mid-flight aborts for tests).
         Checkpoint/resume is cohort-engine only."""
-        if not isinstance(spec, ExperimentSpec):
-            raise TypeError(f"Session.run takes an ExperimentSpec: {spec!r}")
-        tb, b = spec.testbed, spec.run
-        checkpoint = None
-        if checkpoint_every is not None:
-            if checkpoint_dir is None:
-                raise ValueError(
-                    "checkpoint_every requires checkpoint_dir (where the "
-                    "step_*.npz snapshots go)")
-            from repro.engine.resilience import CheckpointPolicy
-            checkpoint = CheckpointPolicy(
-                directory=checkpoint_dir, every=checkpoint_every,
-                keep_last=keep_last, crash_after_saves=crash_after_saves)
-        self._materialize(tb)
-        clients, params0 = self._clients, self._params0
-        acc_fn, pooled = self._acc_fn, self._pooled
-        self.events["runs"] += 1
-        # route by the strategy's sync/async nature, not its name: the
-        # robust fedavg variants (fedavg_trimmed) are barrier loops too
-        strategy = spec.strategy.make()
-        if spec.backend == "legacy":
-            if spec.engine.mesh is not None:
-                raise ValueError("mesh execution requires backend='cohort'")
-            if checkpoint is not None or resume_from is not None:
-                raise ValueError(
-                    "checkpoint/resume requires backend='cohort' — the "
-                    "legacy reference loop has no snapshot support")
-            from repro.core.server import run_async, run_fedavg
-            if not strategy.is_async:
-                return run_fedavg(
-                    clients, params0, acc_fn, pooled, rounds=b.rounds,
+        with spans.span("fl.session.run"):
+            if not isinstance(spec, ExperimentSpec):
+                raise TypeError(
+                    f"Session.run takes an ExperimentSpec: {spec!r}")
+            tb, b = spec.testbed, spec.run
+            checkpoint = None
+            if checkpoint_every is not None:
+                if checkpoint_dir is None:
+                    raise ValueError(
+                        "checkpoint_every requires checkpoint_dir (where the "
+                        "step_*.npz snapshots go)")
+                from repro.engine.resilience import CheckpointPolicy
+                checkpoint = CheckpointPolicy(
+                    directory=checkpoint_dir, every=checkpoint_every,
+                    keep_last=keep_last, crash_after_saves=crash_after_saves)
+            self._materialize(tb)
+            clients, params0 = self._clients, self._params0
+            acc_fn, pooled = self._acc_fn, self._pooled
+            self.events["runs"] += 1
+            # route by the strategy's sync/async nature, not its name: the
+            # robust fedavg variants (fedavg_trimmed) are barrier loops too
+            strategy = spec.strategy.make()
+            if spec.backend == "legacy":
+                if spec.engine.mesh is not None:
+                    raise ValueError(
+                        "mesh execution requires backend='cohort'")
+                if checkpoint is not None or resume_from is not None:
+                    raise ValueError(
+                        "checkpoint/resume requires backend='cohort' — the "
+                        "legacy reference loop has no snapshot support")
+                from repro.core.server import run_async, run_fedavg
+                if not strategy.is_async:
+                    return run_fedavg(
+                        clients, params0, acc_fn, pooled, rounds=b.rounds,
+                        seed=tb.seed, eval_every=b.eval_every,
+                        target_acc=b.target_acc, engine="legacy",
+                        faults=tb.faults, strategy=strategy,
+                        screening=tb.screening)
+                return run_async(
+                    clients, params0, acc_fn, pooled, strategy,
+                    max_updates=b.max_updates, max_time=b.max_time,
                     seed=tb.seed, eval_every=b.eval_every,
                     target_acc=b.target_acc, engine="legacy",
-                    faults=tb.faults, strategy=strategy,
-                    screening=tb.screening)
-            return run_async(
+                    faults=tb.faults, screening=tb.screening)
+            from repro.engine import run_async_engine, run_fedavg_engine
+            runner = self._get_runner(tb, spec.engine)
+            if not strategy.is_async:
+                return run_fedavg_engine(
+                    clients, params0, acc_fn, pooled, rounds=b.rounds,
+                    seed=tb.seed, eval_every=b.eval_every,
+                    target_acc=b.target_acc, runner=runner, faults=tb.faults,
+                    checkpoint=checkpoint, resume_from=resume_from,
+                    strategy=strategy, screening=tb.screening)
+            return run_async_engine(
                 clients, params0, acc_fn, pooled, strategy,
-                max_updates=b.max_updates, max_time=b.max_time, seed=tb.seed,
-                eval_every=b.eval_every, target_acc=b.target_acc,
-                engine="legacy", faults=tb.faults, screening=tb.screening)
-        from repro.engine import run_async_engine, run_fedavg_engine
-        runner = self._get_runner(tb, spec.engine)
-        if not strategy.is_async:
-            return run_fedavg_engine(
-                clients, params0, acc_fn, pooled, rounds=b.rounds,
+                max_updates=b.max_updates, max_time=b.max_time,
                 seed=tb.seed, eval_every=b.eval_every,
                 target_acc=b.target_acc, runner=runner, faults=tb.faults,
                 checkpoint=checkpoint, resume_from=resume_from,
-                strategy=strategy, screening=tb.screening)
-        return run_async_engine(
-            clients, params0, acc_fn, pooled, strategy,
-            max_updates=b.max_updates, max_time=b.max_time, seed=tb.seed,
-            eval_every=b.eval_every, target_acc=b.target_acc, runner=runner,
-            faults=tb.faults, checkpoint=checkpoint, resume_from=resume_from,
-            screening=tb.screening)
+                screening=tb.screening)
 
     def sweep(self, spec: ExperimentSpec, axes: dict) -> SweepResult:
         """Run the cartesian grid of ``spec`` with ``axes`` mapping dotted

@@ -128,11 +128,11 @@ def _register_builtins():
 
     def _linear_accuracy(cfg):
         @jax.jit
-        def _acc(params, data):
+        def eval_accuracy(params, data):
             logits = jax.vmap(lambda x: _linear_logits(params, x))(data["x"])
             return jnp.mean(
                 (jnp.argmax(logits, -1) == data["y"]).astype(jnp.float32))
-        return _acc
+        return eval_accuracy
 
     register_workload(
         "ser_linear",

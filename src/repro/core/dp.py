@@ -129,7 +129,8 @@ def dp_mean_gradient(
     norm (useful for calibrating C) and the fraction of clipped samples.
     """
     validate_dp_path(dp_path)
-    g_per = per_example_grads(loss_fn, params, batch)
+    with jax.named_scope("dp_grads"):
+        g_per = per_example_grads(loss_fn, params, batch)
     bsz = jax.tree_util.tree_leaves(g_per)[0].shape[0]
     stddev = (cfg.noise_multiplier * cfg.clip_norm / bsz
               if noise_stddev is None else noise_stddev)
@@ -137,48 +138,52 @@ def dp_mean_gradient(
     # noise; a traced scalar always takes the noised program.
     add_noise = not (isinstance(stddev, (int, float)) and stddev == 0.0)
 
-    if dp_path == "pallas":
-        # fused Pallas path: flatten per-example grads to (1, B, D) and run
-        # the two-pass cohort clip+mean(+noise) kernel with K=1 (see
-        # repro.kernels.dp_clip).  Noise draws replay noise_tree's split
-        # order so both paths agree to float tolerance.
-        from repro.kernels.dp_clip.ops import dp_clip_mean_noise_cohort
-        from repro.pytree import (
-            tree_gaussian_vector_like, tree_unflatten_from_vector)
+    with jax.named_scope("dp_clip_noise"):
+        if dp_path == "pallas":
+            # fused Pallas path: flatten per-example grads to (1, B, D)
+            # and run the two-pass cohort clip+mean(+noise) kernel with
+            # K=1 (see repro.kernels.dp_clip).  Noise draws replay
+            # noise_tree's split order so both paths agree to float
+            # tolerance.
+            from repro.kernels.dp_clip.ops import dp_clip_mean_noise_cohort
+            from repro.pytree import (
+                tree_gaussian_vector_like, tree_unflatten_from_vector)
 
-        leaves = jax.tree_util.tree_leaves(g_per)
-        flat = jnp.concatenate(
-            [l.reshape(bsz, -1).astype(jnp.float32) for l in leaves], axis=1
-        )
-        template = jax.tree_util.tree_map(lambda l: l[0], g_per)
-        if add_noise:
-            z = tree_gaussian_vector_like(key, template)
-            mean_flat, nrm, frac = dp_clip_mean_noise_cohort(
-                flat[None], cfg.clip_norm,
-                jnp.asarray(stddev, jnp.float32), z[None])
+            leaves = jax.tree_util.tree_leaves(g_per)
+            flat = jnp.concatenate(
+                [l.reshape(bsz, -1).astype(jnp.float32) for l in leaves],
+                axis=1)
+            template = jax.tree_util.tree_map(lambda l: l[0], g_per)
+            if add_noise:
+                z = tree_gaussian_vector_like(key, template)
+                mean_flat, nrm, frac = dp_clip_mean_noise_cohort(
+                    flat[None], cfg.clip_norm,
+                    jnp.asarray(stddev, jnp.float32), z[None])
+            else:
+                mean_flat, nrm, frac = dp_clip_mean_noise_cohort(
+                    flat[None], cfg.clip_norm)
+            noised = tree_unflatten_from_vector(mean_flat[0], template)
+            return noised, {"mean_grad_norm": nrm[0],
+                            "clip_fraction": frac[0]}
         else:
-            mean_flat, nrm, frac = dp_clip_mean_noise_cohort(
-                flat[None], cfg.clip_norm)
-        noised = tree_unflatten_from_vector(mean_flat[0], template)
-        return noised, {"mean_grad_norm": nrm[0], "clip_fraction": frac[0]}
-    else:
-        # per-sample norms over ALL leaves (flatten the non-batch dims)
-        sq = sum(
-            jnp.sum(jnp.square(l.astype(jnp.float32)).reshape(bsz, -1), axis=1)
-            for l in jax.tree_util.tree_leaves(g_per)
-        )
-        norms = jnp.sqrt(sq)                                   # (B,)
-        scales = 1.0 / jnp.maximum(1.0, norms / cfg.clip_norm)  # (B,)
-        mean = jax.tree_util.tree_map(
-            lambda l: jnp.mean(
-                l * scales.reshape((bsz,) + (1,) * (l.ndim - 1)), axis=0
-            ),
-            g_per,
-        )
-        nrm = jnp.mean(norms)
-        frac = jnp.mean((norms > cfg.clip_norm).astype(jnp.float32))
+            # per-sample norms over ALL leaves (flatten the non-batch dims)
+            sq = sum(
+                jnp.sum(jnp.square(l.astype(jnp.float32)).reshape(bsz, -1),
+                        axis=1)
+                for l in jax.tree_util.tree_leaves(g_per)
+            )
+            norms = jnp.sqrt(sq)                                   # (B,)
+            scales = 1.0 / jnp.maximum(1.0, norms / cfg.clip_norm)  # (B,)
+            mean = jax.tree_util.tree_map(
+                lambda l: jnp.mean(
+                    l * scales.reshape((bsz,) + (1,) * (l.ndim - 1)), axis=0
+                ),
+                g_per,
+            )
+            nrm = jnp.mean(norms)
+            frac = jnp.mean((norms > cfg.clip_norm).astype(jnp.float32))
 
-    noised = noise_tree(key, mean, stddev) if add_noise else mean
+        noised = noise_tree(key, mean, stddev) if add_noise else mean
     return noised, {"mean_grad_norm": nrm, "clip_fraction": frac}
 
 

@@ -156,14 +156,15 @@ def _screen_members(ref, new):
     config scalars, so one compiled program serves screening on/off and
     every threshold.  ``repro.core.screening.screen_update`` is the
     legacy loops' host-side mirror (same leaf-order accumulation)."""
-    sq = None
-    for p0, p in zip(jax.tree_util.tree_leaves(ref),
-                     jax.tree_util.tree_leaves(new)):
-        d = p.astype(jnp.float32) - p0.astype(jnp.float32)
-        part = jnp.sum(d * d, axis=tuple(range(1, d.ndim)))    # (K,)
-        sq = part if sq is None else sq + part
-    norm = jnp.sqrt(sq)
-    return jnp.isfinite(norm), norm
+    with jax.named_scope("screen"):
+        sq = None
+        for p0, p in zip(jax.tree_util.tree_leaves(ref),
+                         jax.tree_util.tree_leaves(new)):
+            d = p.astype(jnp.float32) - p0.astype(jnp.float32)
+            part = jnp.sum(d * d, axis=tuple(range(1, d.ndim)))    # (K,)
+            sq = part if sq is None else sq + part
+        norm = jnp.sqrt(sq)
+        return jnp.isfinite(norm), norm
 
 
 def _tree_where_members(live, new, old):
@@ -366,7 +367,8 @@ def make_cohort_step(loss_fn: Callable, dp_cfg: DPConfig, opt,
             grad = jax.grad(
                 lambda p: jnp.mean(
                     jax.vmap(lambda ex: loss_fn(p, ex))(batch)))(params)
-        return opt.update(grad, opt_state, params)
+        with jax.named_scope("optimizer"):
+            return opt.update(grad, opt_state, params)
 
     def local_phase(params, opt_state, key, batches, n_steps, noise_stddev):
         """One member's whole local round, fused across minibatch steps."""
@@ -470,23 +472,27 @@ def make_cohort_step(loss_fn: Callable, dp_cfg: DPConfig, opt,
             live = step_i < n_steps                       # (K,)
             splits = jax.vmap(jax.random.split)(ks)       # (K, 2, key)
             k_next, subs = splits[:, 0], splits[:, 1]
-            g_per = jax.vmap(
-                lambda p, b: per_example_grads(loss_fn, p, b))(
-                    stacked_params, batch_s)              # leaves (K, B, ...)
-            leaves = jax.tree_util.tree_leaves(g_per)
-            K, bsz = leaves[0].shape[0], leaves[0].shape[1]
-            flat = jnp.concatenate(
-                [l.reshape(K, bsz, -1).astype(jnp.float32) for l in leaves],
-                axis=2)                                   # (K, B, D)
-            template = jax.tree_util.tree_map(lambda l: l[0, 0], g_per)
-            noise = ()
-            if add_noise:
-                noise = (noise_stddev, jax.vmap(
-                    lambda k: tree_gaussian_vector_like(k, template))(subs))
-            means = kernel_members(flat, *noise)
-            grads = _unflatten_members(means, template)
-            p_new, o_new = jax.vmap(opt.update)(
-                grads, stacked_opt, stacked_params)
+            with jax.named_scope("dp_grads"):
+                g_per = jax.vmap(
+                    lambda p, b: per_example_grads(loss_fn, p, b))(
+                        stacked_params, batch_s)          # leaves (K, B, ...)
+            with jax.named_scope("dp_clip_noise"):
+                leaves = jax.tree_util.tree_leaves(g_per)
+                K, bsz = leaves[0].shape[0], leaves[0].shape[1]
+                flat = jnp.concatenate(
+                    [l.reshape(K, bsz, -1).astype(jnp.float32)
+                     for l in leaves], axis=2)            # (K, B, D)
+                template = jax.tree_util.tree_map(lambda l: l[0, 0], g_per)
+                noise = ()
+                if add_noise:
+                    noise = (noise_stddev, jax.vmap(
+                        lambda k: tree_gaussian_vector_like(k, template))(
+                            subs))
+                means = kernel_members(flat, *noise)
+                grads = _unflatten_members(means, template)
+            with jax.named_scope("optimizer"):
+                p_new, o_new = jax.vmap(opt.update)(
+                    grads, stacked_opt, stacked_params)
             return (_tree_where_members(live, p_new, stacked_params),
                     _tree_where_members(live, o_new, stacked_opt),
                     jnp.where(live[:, None], k_next, ks))

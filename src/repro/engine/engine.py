@@ -84,6 +84,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import accountant as _accountant
+from repro.core import spans
 from repro.core.aggregation import (
     AdaptiveAsync, FedAsync, FedAvg, FedBuff, apply_update)
 from repro.core.faults import FaultInjector, apply_deadline, zero_fault_stats
@@ -582,11 +583,12 @@ class CohortRunner:
         k = min(self.store.lookahead, len(heap))
         if k <= 0:
             return
-        head = [heapq.heappop(heap) for _ in range(k)]
-        for entry in head:
-            heapq.heappush(heap, entry)
-        self.store.prefetch_cids(
-            [cid for _, cid in head if cid in pending])
+        with spans.span("fl.prefetch"):
+            head = [heapq.heappop(heap) for _ in range(k)]
+            for entry in head:
+                heapq.heappush(heap, entry)
+            self.store.prefetch_cids(
+                [cid for _, cid in head if cid in pending])
 
     def prefetch_plans(self, plans):
         """Lookahead prefetch for the fedavg barrier: stage the NEXT
@@ -594,7 +596,8 @@ class CohortRunner:
         same-round plans may be passed — a cross-round prefetch would
         stage the previous round's globals."""
         if self.store is not None:
-            self.store.prefetch_cids([p.cid for p in plans])
+            with spans.span("fl.prefetch"):
+                self.store.prefetch_cids([p.cid for p in plans])
 
     def _flush_writes(self):
         q, self._writeq = self._writeq, []
@@ -753,76 +756,82 @@ class CohortRunner:
         Pure w.r.t. the compiled step: staging cohort t+1 while cohort t
         executes is safe because every input is host-deterministic plan
         state (the pipelined scheduler's lookahead relies on it)."""
-        self._materialize_plans(plans)
-        k = len(plans)
-        if not self.use_arena:
+        with spans.span("fl.stage"):
+            self._materialize_plans(plans)
+            k = len(plans)
+            if not self.use_arena:
+                if self.s_max == 0:  # degenerate: no client has a full batch
+                    return StagedCohort(plans=plans, k=k, degenerate=True,
+                                        arena=False)
+                member_batches = []
+                for p in plans:
+                    c = self.clients[p.cid]
+                    idx = self._pad_idx(p.batch_idx, c.batch_size)
+                    member_batches.append(
+                        {kk: v[idx] for kk, v in c.data.items()})
+                batches_np = {
+                    kk: np.stack([mb[kk] for mb in member_batches])
+                    for kk in member_batches[0]
+                }
+                self.cohorts_run += 1
+                self.h2d_bytes_total += (
+                    sum(a.nbytes for a in batches_np.values()) + 4 * k + 4 * k)
+                return StagedCohort(
+                    plans=plans, k=k, arena=False,
+                    stacked_params=stack_trees([p.params0 for p in plans]),
+                    stacked_opt=stack_trees([p.opt_state for p in plans]),
+                    batches={kk: jnp.asarray(v)
+                             for kk, v in batches_np.items()},
+                    keys=jnp.stack([p.key for p in plans]),
+                    n_steps=jnp.asarray([p.n_steps for p in plans],
+                                        jnp.int32),
+                    corrupt=jnp.asarray(
+                        [p.corrupt_scale for p in plans], jnp.float32))
+            # slot resolution precedes the flush: the tiered store's
+            # acquire queues params writes for faulted-in members, and
+            # those must ride THIS cohort's flush (the all-resident path
+            # queues nothing here, so the flush point is unchanged for it)
+            if self.store is not None:
+                member_slots = self.store.acquire_cohort(
+                    [p.cid for p in plans])
+            else:
+                member_slots = [p.cid for p in plans]
+            self._flush_writes()
+            k_pad = (padded_cohort_size(k, self._n_data,
+                                        self.cfg.pow2_cohorts)
+                     if self._n_data > 1 else k)
+            slots = np.full((k_pad,), self.pad_slot, np.int32)
+            slots[:k] = member_slots
+            slots_j = jnp.asarray(slots)
+            data_slots_j = slots_j
+            dslots = None
+            if not self._data_slots_identical:
+                dslots = np.full((k_pad,), self.data_arena.pad_slot, np.int32)
+                dslots[:k] = self._data_slot_of[[p.cid for p in plans]]
+                data_slots_j = jnp.asarray(dslots)
             if self.s_max == 0:  # degenerate: no client has a full batch
                 return StagedCohort(plans=plans, k=k, degenerate=True,
-                                    arena=False)
-            member_batches = []
-            for p in plans:
-                c = self.clients[p.cid]
-                idx = self._pad_idx(p.batch_idx, c.batch_size)
-                member_batches.append({kk: v[idx] for kk, v in c.data.items()})
-            batches_np = {
-                kk: np.stack([mb[kk] for mb in member_batches])
-                for kk in member_batches[0]
-            }
+                                    slots=slots_j, data_slots=data_slots_j)
+            batch_size = self.clients[0].batch_size
+            batch_idx = np.zeros((k_pad, self.s_max, batch_size), np.int32)
+            for i, p in enumerate(plans):
+                batch_idx[i] = self._pad_idx(p.batch_idx, batch_size)
+            n_steps = np.zeros((k_pad,), np.int32)
+            n_steps[:k] = [p.n_steps for p in plans]
+            keys = jnp.stack(
+                [p.key for p in plans]
+                + [jnp.zeros_like(plans[0].key)] * (k_pad - k))
+            scales = np.ones((k_pad,), np.float32)  # pad members stay clean
+            scales[:k] = [p.corrupt_scale for p in plans]
             self.cohorts_run += 1
-            self.h2d_bytes_total += (
-                sum(a.nbytes for a in batches_np.values()) + 4 * k + 4 * k)
+            self.h2d_bytes_total += (batch_idx.nbytes + slots.nbytes
+                                     + n_steps.nbytes + scales.nbytes
+                                     + (dslots.nbytes if dslots is not None
+                                        else 0))
             return StagedCohort(
-                plans=plans, k=k, arena=False,
-                stacked_params=stack_trees([p.params0 for p in plans]),
-                stacked_opt=stack_trees([p.opt_state for p in plans]),
-                batches={kk: jnp.asarray(v) for kk, v in batches_np.items()},
-                keys=jnp.stack([p.key for p in plans]),
-                n_steps=jnp.asarray([p.n_steps for p in plans], jnp.int32),
-                corrupt=jnp.asarray(
-                    [p.corrupt_scale for p in plans], jnp.float32))
-        # slot resolution precedes the flush: the tiered store's acquire
-        # queues params writes for faulted-in members, and those must ride
-        # THIS cohort's flush (the all-resident path queues nothing here,
-        # so the flush point is unchanged for it)
-        if self.store is not None:
-            member_slots = self.store.acquire_cohort([p.cid for p in plans])
-        else:
-            member_slots = [p.cid for p in plans]
-        self._flush_writes()
-        k_pad = (padded_cohort_size(k, self._n_data, self.cfg.pow2_cohorts)
-                 if self._n_data > 1 else k)
-        slots = np.full((k_pad,), self.pad_slot, np.int32)
-        slots[:k] = member_slots
-        slots_j = jnp.asarray(slots)
-        data_slots_j = slots_j
-        dslots = None
-        if not self._data_slots_identical:
-            dslots = np.full((k_pad,), self.data_arena.pad_slot, np.int32)
-            dslots[:k] = self._data_slot_of[[p.cid for p in plans]]
-            data_slots_j = jnp.asarray(dslots)
-        if self.s_max == 0:  # degenerate: no client has a full batch
-            return StagedCohort(plans=plans, k=k, degenerate=True,
-                                slots=slots_j, data_slots=data_slots_j)
-        batch_size = self.clients[0].batch_size
-        batch_idx = np.zeros((k_pad, self.s_max, batch_size), np.int32)
-        for i, p in enumerate(plans):
-            batch_idx[i] = self._pad_idx(p.batch_idx, batch_size)
-        n_steps = np.zeros((k_pad,), np.int32)
-        n_steps[:k] = [p.n_steps for p in plans]
-        keys = jnp.stack(
-            [p.key for p in plans]
-            + [jnp.zeros_like(plans[0].key)] * (k_pad - k))
-        scales = np.ones((k_pad,), np.float32)  # pad members stay clean
-        scales[:k] = [p.corrupt_scale for p in plans]
-        self.cohorts_run += 1
-        self.h2d_bytes_total += (batch_idx.nbytes + slots.nbytes
-                                 + n_steps.nbytes + scales.nbytes
-                                 + (dslots.nbytes if dslots is not None
-                                    else 0))
-        return StagedCohort(
-            plans=plans, k=k, slots=slots_j, data_slots=data_slots_j,
-            batch_idx=jnp.asarray(batch_idx), keys=keys,
-            n_steps=jnp.asarray(n_steps), corrupt=jnp.asarray(scales))
+                plans=plans, k=k, slots=slots_j, data_slots=data_slots_j,
+                batch_idx=jnp.asarray(batch_idx), keys=keys,
+                n_steps=jnp.asarray(n_steps), corrupt=jnp.asarray(scales))
 
     def submit_cohort(self, staged: StagedCohort):
         """Enqueue the compiled local phase for a staged cohort.  On the
@@ -831,35 +840,37 @@ class CohortRunner:
         until the cohort finishes — each such submit is counted as a
         ``blocking_submits`` host sync (between evals, where the hot
         loop lives)."""
-        plans = staged.plans
-        if not staged.degenerate and self._submits_block:
-            self.blocking_submits += 1
-            self.note_host_sync()
-        if not staged.arena:
+        with spans.span("fl.submit"):
+            plans = staged.plans
+            if not staged.degenerate and self._submits_block:
+                self.blocking_submits += 1
+                self.note_host_sync()
+            if not staged.arena:
+                if staged.degenerate:
+                    self._last_screen = None
+                    return stack_trees([p.params0 for p in plans])
+                new_stacked, new_opt, screen = self.cohort_step(
+                    staged.stacked_params, staged.stacked_opt, staged.batches,
+                    staged.keys, staged.n_steps, self._noise_std,
+                    staged.corrupt)
+                for i, p in enumerate(plans):
+                    self.clients[p.cid].opt_state = unstack_tree(new_opt, i)
+                self._last_screen = screen
+                return new_stacked
             if staged.degenerate:
                 self._last_screen = None
-                return stack_trees([p.params0 for p in plans])
-            new_stacked, new_opt, screen = self.cohort_step(
-                staged.stacked_params, staged.stacked_opt, staged.batches,
-                staged.keys, staged.n_steps, self._noise_std, staged.corrupt)
-            for i, p in enumerate(plans):
-                self.clients[p.cid].opt_state = unstack_tree(new_opt, i)
+                return self._gather(self._arena_params, staged.slots)
+            new_stacked, self._arena_opt, screen = self.cohort_step(
+                self._arena_params, self._arena_opt, self._arena_data,
+                staged.slots, staged.data_slots, staged.batch_idx, staged.keys,
+                staged.n_steps, self._noise_std, staged.corrupt)
+            if self.store is not None:
+                # every real member's arena opt row was just scatter-updated
+                # (dropped/screened members trained too — only their upload
+                # was discarded), so eviction must spill before reuse
+                self.store.note_trained([p.cid for p in staged.plans])
             self._last_screen = screen
             return new_stacked
-        if staged.degenerate:
-            self._last_screen = None
-            return self._gather(self._arena_params, staged.slots)
-        new_stacked, self._arena_opt, screen = self.cohort_step(
-            self._arena_params, self._arena_opt, self._arena_data,
-            staged.slots, staged.data_slots, staged.batch_idx, staged.keys,
-            staged.n_steps, self._noise_std, staged.corrupt)
-        if self.store is not None:
-            # every real member's arena opt row was just scatter-updated
-            # (dropped/screened members trained too — only their upload
-            # was discarded), so eviction must spill before reuse
-            self.store.note_trained([p.cid for p in staged.plans])
-        self._last_screen = screen
-        return new_stacked
 
     def take_screen_handle(self):
         """Return-and-clear the device handle for the LAST submitted
@@ -988,28 +999,29 @@ def run_fedavg_engine(
         raise ValueError(
             f"run_fedavg_engine requires a synchronous strategy, got "
             f"{strategy.name!r} — use run_async_engine")
-    injector = (FaultInjector(faults, len(clients))
-                if faults is not None else None)
-    runner.fault_injector = injector
-    screener = (ScreeningState(screening, len(clients))
-                if screening is not None else None)
-    runner.screening = screener
-    log = RunLog(strategy=strategy.name)
-    key = jax.random.PRNGKey(seed)
-    t_virtual = 0.0
-    for c in clients:
-        log.update_counts[c.tier] = 0
-        log.staleness.setdefault(c.tier, [])
-        log.eps_trajectory.setdefault(c.tier, [])
+    with spans.span("fl.startup"):
+        injector = (FaultInjector(faults, len(clients))
+                    if faults is not None else None)
+        runner.fault_injector = injector
+        screener = (ScreeningState(screening, len(clients))
+                    if screening is not None else None)
+        runner.screening = screener
+        log = RunLog(strategy=strategy.name)
+        key = jax.random.PRNGKey(seed)
+        t_virtual = 0.0
+        for c in clients:
+            log.update_counts[c.tier] = 0
+            log.staleness.setdefault(c.tier, [])
+            log.eps_trajectory.setdefault(c.tier, [])
 
-    start_rnd = 1
-    if resume_from is not None:
-        from repro.engine import resilience as _rez
-        global_params, key, t_virtual, rnd0 = _rez.restore_fedavg(
-            resume_from, runner, clients, log, injector, global_params)
-        start_rnd = rnd0 + 1
-        if checkpoint is not None:
-            checkpoint.mark(rnd0)
+        start_rnd = 1
+        if resume_from is not None:
+            from repro.engine import resilience as _rez
+            global_params, key, t_virtual, rnd0 = _rez.restore_fedavg(
+                resume_from, runner, clients, log, injector, global_params)
+            start_rnd = rnd0 + 1
+            if checkpoint is not None:
+                checkpoint.mark(rnd0)
 
     # pipelined submit/drain across rounds: the barrier is algorithmic
     # (round r+1 trains from round r's merged globals) but not a host
@@ -1018,145 +1030,177 @@ def run_fedavg_engine(
     # compiled work stay in flight between eval boundaries
     inflight = deque()
     for rnd in range(start_rnd, rounds + 1):
-        plans = []
-        # one compiled scan for the round's whole PRNG chain (bitwise the
-        # old per-client split loop; O(1) dispatches instead of O(N))
-        key, subs = split_key_chain(key, len(clients))
-        for c, sub in zip(clients, subs):
-            p = runner.dispatch(c, global_params, sub, rnd - 1)
-            if injector is not None and rnd > 1:
-                # leave/rejoin churn: the member rejoins late, stretching
-                # its whole barrier round (the initial round never draws)
-                p.duration += injector.redispatch_delay(c.cid, t_virtual)
-            plans.append(p)
-        # per-plan delivery times for the screening ledger (None = the
-        # upload never arrived, so there is nothing to screen)
-        t_round0 = t_virtual
-        t_deliver = [t_round0 + p.duration for p in plans]
-        if injector is not None:
-            # fates resolve BEFORE staging so a delivered member's
-            # transit-corruption scale rides into the compiled step's
-            # runtime corrupt vector (the draws are host-only state, so
-            # the event sequence is identical to the submit-first order
-            # earlier revisions used)
-            fates = [injector.fedavg_fate(p.cid, t_virtual, p.duration)
-                     for p in plans]
-            offsets = [off for off, _ in fates]
-            keep, round_time = apply_deadline(injector.model, offsets)
-            for i, (p, off, kept) in enumerate(zip(plans, offsets, keep)):
-                p.dropped = not kept
-                t_deliver[i] = None if off is None else t_round0 + off
-                if off is not None:
-                    p.corrupt_scale = injector.take_corruption(p.cid)
-                    if not kept:
-                        injector.note_deadline_drop(p.cid, t_round0 + off)
-            if any(p.dropped for p in plans):
-                injector.note_degraded()
-            # the barrier waits for the effective deadline when it cut
-            # anyone off, else the slowest surviving delivery; a round
-            # that lost EVERY update still burns the full barrier wait
-            t_virtual += (round_time if round_time is not None
-                          else max(p.duration for p in plans))
-        else:
-            t_virtual += max(p.duration for p in plans)
-        chunks = [plans[i:i + cfg.max_cohort]
-                  for i in range(0, len(plans), cfg.max_cohort)]
-        stacked_chunks, screen_handles = [], []
-        for ci, ch in enumerate(chunks):
-            stacked_chunks.append(
-                runner.submit_cohort(runner.stage_cohort(ch)))
-            screen_handles.append(runner.take_screen_handle())
-            if ci + 1 < len(chunks):
-                # tiered store: stage the NEXT chunk's hot slots while
-                # this chunk's compiled step executes (same-round plans
-                # only — their dispatch-time globals are current)
-                runner.prefetch_plans(chunks[ci + 1])
-        log.cohort_sizes.extend(len(ch) for ch in chunks)
-        if screener is not None:
-            # judge every DELIVERED member against the compiled step's
-            # finite-mask/update-norm outputs (one fetch per chunk, the
-            # screen_verdict_syncs bucket); a reject keeps its compiled
-            # slot and merges with coefficient exactly 0.0 below
-            i0 = 0
-            for ch, handle in zip(chunks, screen_handles):
-                fin, nrm = runner.fetch_screen(handle, len(ch))
-                for j, p in enumerate(ch):
-                    if not p.dropped and not screener.screen(
-                            p.cid, t_deliver[i0 + j], fin[j], nrm[j]):
-                        p.dropped = True
-                i0 += len(ch)
+        with spans.step_span("fl.round", rnd):
+            with spans.span("fl.dispatch"):
+                plans = []
+                # one compiled scan for the round's whole PRNG chain
+                # (bitwise the old per-client split loop; O(1) dispatches
+                # instead of O(N))
+                key, subs = split_key_chain(key, len(clients))
+                for c, sub in zip(clients, subs):
+                    p = runner.dispatch(c, global_params, sub, rnd - 1)
+                    if injector is not None and rnd > 1:
+                        # leave/rejoin churn: the member rejoins late,
+                        # stretching its whole barrier round (the initial
+                        # round never draws)
+                        p.duration += injector.redispatch_delay(
+                            c.cid, t_virtual)
+                    plans.append(p)
+            # per-plan delivery times for the screening ledger (None = the
+            # upload never arrived, so there is nothing to screen)
+            t_round0 = t_virtual
+            t_deliver = [t_round0 + p.duration for p in plans]
+            if injector is not None:
+                # fates resolve BEFORE staging so a delivered member's
+                # transit-corruption scale rides into the compiled step's
+                # runtime corrupt vector (the draws are host-only state,
+                # so the event sequence is identical to the submit-first
+                # order earlier revisions used)
+                with spans.span("fl.pop"):
+                    fates = [injector.fedavg_fate(p.cid, t_virtual,
+                                                  p.duration)
+                             for p in plans]
+                    offsets = [off for off, _ in fates]
+                    keep, round_time = apply_deadline(injector.model,
+                                                      offsets)
+                    for i, (p, off, kept) in enumerate(
+                            zip(plans, offsets, keep)):
+                        p.dropped = not kept
+                        t_deliver[i] = None if off is None else t_round0 + off
+                        if off is not None:
+                            p.corrupt_scale = injector.take_corruption(p.cid)
+                            if not kept:
+                                injector.note_deadline_drop(
+                                    p.cid, t_round0 + off)
+                    if any(p.dropped for p in plans):
+                        injector.note_degraded()
+                # the barrier waits for the effective deadline when it cut
+                # anyone off, else the slowest surviving delivery; a round
+                # that lost EVERY update still burns the full barrier wait
+                t_virtual += (round_time if round_time is not None
+                              else max(p.duration for p in plans))
+            else:
+                t_virtual += max(p.duration for p in plans)
+            chunks = [plans[i:i + cfg.max_cohort]
+                      for i in range(0, len(plans), cfg.max_cohort)]
+            stacked_chunks, screen_handles = [], []
+            for ci, ch in enumerate(chunks):
+                stacked_chunks.append(
+                    runner.submit_cohort(runner.stage_cohort(ch)))
+                screen_handles.append(runner.take_screen_handle())
+                if ci + 1 < len(chunks):
+                    # tiered store: stage the NEXT chunk's hot slots while
+                    # this chunk's compiled step executes (same-round
+                    # plans only — their dispatch-time globals are current)
+                    runner.prefetch_plans(chunks[ci + 1])
+            log.cohort_sizes.extend(len(ch) for ch in chunks)
+            if screener is not None:
+                # judge every DELIVERED member against the compiled step's
+                # finite-mask/update-norm outputs (one fetch per chunk, the
+                # screen_verdict_syncs bucket); a reject keeps its compiled
+                # slot and merges with coefficient exactly 0.0 below
+                with spans.span("fl.screen"):
+                    i0 = 0
+                    for ch, handle in zip(chunks, screen_handles):
+                        fin, nrm = runner.fetch_screen(handle, len(ch))
+                        for j, p in enumerate(ch):
+                            if not p.dropped and not screener.screen(
+                                    p.cid, t_deliver[i0 + j], fin[j],
+                                    nrm[j]):
+                                p.dropped = True
+                        i0 += len(ch)
 
-        if _fused_ok(strategy, clients, plans, cfg):
-            # Eq. 9 as chunked weights-vector reductions: the new globals
-            # accumulate sum_k (n_k / sum n) p_k across the chunks, the
-            # sum running over SURVIVING members only (dropped members
-            # keep their compiled slot with coefficient exactly 0, so a
-            # degraded round re-uses the very same program).
-            # (`merged`, not `acc`: the eval scalar below is `acc` — the
-            # accumulator pytree must never share its name)
-            if any(not p.dropped for p in plans):
-                _, kept_coeffs = fedavg_weights(
-                    [clients[p.cid].n_train for p in plans if not p.dropped])
-                it = iter(kept_coeffs)
-                coeffs = [0.0 if p.dropped else next(it) for p in plans]
-                merged = jax.tree_util.tree_map(jnp.zeros_like, global_params)
-                off = 0
-                for ch, st in zip(chunks, stacked_chunks):
-                    merged = runner.merge_cohort(
-                        merged, st,
-                        _pad_coeffs(coeffs[off:off + len(ch)], st), 1.0)
-                    off += len(ch)
-                global_params = merged
-        else:
-            updates = []
-            for ch, st in zip(chunks, stacked_chunks):
-                updates.extend(
-                    (runner.upload(p, unstack_tree(st, i)),
-                     clients[p.cid].n_train)
-                    for i, p in enumerate(ch) if not p.dropped)
-            if updates:
-                global_params = strategy.aggregate(global_params, updates)
+            with spans.span("fl.merge"):
+                if _fused_ok(strategy, clients, plans, cfg):
+                    # Eq. 9 as chunked weights-vector reductions: the new
+                    # globals accumulate sum_k (n_k / sum n) p_k across
+                    # the chunks, the sum running over SURVIVING members
+                    # only (dropped members keep their compiled slot with
+                    # coefficient exactly 0, so a degraded round re-uses
+                    # the very same program).  (`merged`, not `acc`: the
+                    # eval scalar below is `acc` — the accumulator pytree
+                    # must never share its name)
+                    if any(not p.dropped for p in plans):
+                        _, kept_coeffs = fedavg_weights(
+                            [clients[p.cid].n_train for p in plans
+                             if not p.dropped])
+                        it = iter(kept_coeffs)
+                        coeffs = [0.0 if p.dropped else next(it)
+                                  for p in plans]
+                        merged = jax.tree_util.tree_map(jnp.zeros_like,
+                                                        global_params)
+                        off = 0
+                        for ch, st in zip(chunks, stacked_chunks):
+                            merged = runner.merge_cohort(
+                                merged, st,
+                                _pad_coeffs(coeffs[off:off + len(ch)], st),
+                                1.0)
+                            off += len(ch)
+                        global_params = merged
+                else:
+                    updates = []
+                    for ch, st in zip(chunks, stacked_chunks):
+                        updates.extend(
+                            (runner.upload(p, unstack_tree(st, i)),
+                             clients[p.cid].n_train)
+                            for i, p in enumerate(ch) if not p.dropped)
+                    if updates:
+                        global_params = strategy.aggregate(global_params,
+                                                           updates)
 
-        for p in plans:
-            if p.dropped:
-                continue
-            c = clients[p.cid]
-            log.update_counts[c.tier] += 1
-            log.staleness[c.tier].append(0)  # barrier => no staleness
-            log.eps_trajectory[c.tier].append(p.epsilon)
+            with spans.span("fl.books"):
+                for p in plans:
+                    if p.dropped:
+                        continue
+                    c = clients[p.cid]
+                    log.update_counts[c.tier] += 1
+                    log.staleness[c.tier].append(0)  # barrier: no staleness
+                    log.eps_trajectory[c.tier].append(p.epsilon)
 
-        if rnd % eval_every == 0 or rnd == rounds:
-            runner.eval_boundary(True)
-            acc = _host_fetch(runner, accuracy_fn(global_params, test_data))
-            log.times.append(t_virtual)
-            log.global_acc.append(acc)
-            log.server_version.append(rnd)
-            eval_all(clients, global_params, accuracy_fn, log)
-            runner.eval_boundary(False)
-            inflight.clear()
-            if target_acc is not None and acc >= target_acc:
-                break
-        elif runner.pipelined:
-            inflight.append(jax.tree_util.tree_leaves(global_params))
-            while len(inflight) > cfg.pipeline_depth:
-                runner.drain_waits += 1
-                jax.block_until_ready(inflight.popleft())
+            if rnd % eval_every == 0 or rnd == rounds:
+                with spans.span("fl.eval"):
+                    runner.eval_boundary(True)
+                    acc = _host_fetch(
+                        runner, accuracy_fn(global_params, test_data))
+                    log.times.append(t_virtual)
+                    log.global_acc.append(acc)
+                    log.server_version.append(rnd)
+                    eval_all(clients, global_params, accuracy_fn, log)
+                    runner.eval_boundary(False)
+                inflight.clear()
+                if target_acc is not None and acc >= target_acc:
+                    break
+            elif runner.pipelined:
+                inflight.append(jax.tree_util.tree_leaves(global_params))
+                while len(inflight) > cfg.pipeline_depth:
+                    with spans.span("fl.drain"):
+                        runner.drain_waits += 1
+                        jax.block_until_ready(inflight.popleft())
 
-        if checkpoint is not None and rnd < rounds and checkpoint.due(rnd):
-            from repro.engine import resilience as _rez
-            _rez.save_fedavg(checkpoint, runner, clients, log, injector,
-                             global_params, key, t_virtual, rnd)
+            if checkpoint is not None and rnd < rounds and checkpoint.due(rnd):
+                from repro.engine import resilience as _rez
+                with spans.span("fl.checkpoint"):
+                    _rez.save_fedavg(checkpoint, runner, clients, log,
+                                     injector, global_params, key, t_virtual,
+                                     rnd)
 
-    for c in clients:
-        log.resources[c.tier] = c.clock.resource_sample()
-        log.dropouts[c.tier] = c.clock.dropouts
-    if injector is not None or screener is not None:
-        ev = list(injector.events) if injector is not None else []
-        if screener is not None:
-            ev += list(screener.events)
-        log.fault_events = ev
-    log.engine_stats = validate_engine_stats(runner.stats())
+    _finish(runner, clients, log, injector, screener)
     return global_params, log
+
+
+def _finish(runner, clients, log: RunLog, injector, screener):
+    """After an engine loop: each tier's resource sample and dropouts,
+    the fault and screening events, and the runner's counters."""
+    with spans.span("fl.finish"):
+        for c in clients:
+            log.resources[c.tier] = c.clock.resource_sample()
+            log.dropouts[c.tier] = c.clock.dropouts
+        if injector is not None or screener is not None:
+            ev = list(injector.events) if injector is not None else []
+            if screener is not None:
+                ev += list(screener.events)
+            log.fault_events = ev
+        log.engine_stats = validate_engine_stats(runner.stats())
 
 
 def run_async_engine(
@@ -1213,54 +1257,57 @@ def run_async_engine(
         raise ValueError(
             "checkpoint/resume does not support FedBuff — its cross-cohort "
             "buffer holds live device trees the snapshot cannot capture")
-    injector = (FaultInjector(faults, len(clients))
-                if faults is not None else None)
-    runner.fault_injector = injector
-    screener = (ScreeningState(screening, len(clients))
-                if screening is not None else None)
-    runner.screening = screener
-    if runner.donates_globals:
-        # the fused merge donates its globals argument; copy ONCE so the
-        # first merge consumes our copy, not the caller's buffers (which
-        # the caller may still read — e.g. a baseline eval or a second
-        # run from the same initial params)
-        global_params = jax.tree_util.tree_map(jnp.copy, global_params)
-    log = RunLog(strategy=strategy.name)
-    key = jax.random.PRNGKey(seed)
-    for c in clients:
-        log.update_counts[c.tier] = 0
-        log.influence.setdefault(c.tier, 0.0)
-        log.staleness.setdefault(c.tier, [])
-        log.eps_trajectory.setdefault(c.tier, [])
+    with spans.span("fl.startup"):
+        injector = (FaultInjector(faults, len(clients))
+                    if faults is not None else None)
+        runner.fault_injector = injector
+        screener = (ScreeningState(screening, len(clients))
+                    if screening is not None else None)
+        runner.screening = screener
+        if runner.donates_globals:
+            # the fused merge donates its globals argument; copy ONCE so
+            # the first merge consumes our copy, not the caller's buffers
+            # (which the caller may still read — e.g. a baseline eval or
+            # a second run from the same initial params)
+            global_params = jax.tree_util.tree_map(jnp.copy, global_params)
+        log = RunLog(strategy=strategy.name)
+        key = jax.random.PRNGKey(seed)
+        for c in clients:
+            log.update_counts[c.tier] = 0
+            log.influence.setdefault(c.tier, 0.0)
+            log.staleness.setdefault(c.tier, [])
+            log.eps_trajectory.setdefault(c.tier, [])
 
-    # Seed the event queue: every client starts training version 0 at t=0.
-    heap, pending = [], {}
-    server_version = 0
-    t_virtual = 0.0
-    if resume_from is not None:
-        from repro.engine import resilience as _rez
-        global_params, key, t_virtual, server_version = _rez.restore_async(
-            resume_from, runner, clients, log, injector, global_params,
-            heap, pending)
-        if checkpoint is not None:
-            checkpoint.mark(sum(log.update_counts.values()))
-    else:
-        # startup schedule: one compiled scan for the N-wide PRNG chain
-        # (bitwise the old per-client split loop), O(1)-per-client
-        # dispatches (the batch permutations materialize lazily at
-        # staging), and a single O(N) heapify instead of N pushes —
-        # pop-order-identical since every (duration, cid) is distinct
-        key, subs = split_key_chain(key, len(clients))
-        entries = []
-        for c, sub in zip(clients, subs):
-            plan = runner.dispatch(c, global_params, sub, server_version)
-            pending[c.cid] = plan
-            entries.append((plan.duration, c.cid))
-        heap.extend(entries)
-        heapq.heapify(heap)
-        # tiered store: warm the hot set for the first cohorts (restore
-        # skips this — the snapshot already reflects it)
-        runner.prefetch_upcoming(heap, pending)
+        # Seed the event queue: every client starts training version 0
+        # at t=0.
+        heap, pending = [], {}
+        server_version = 0
+        t_virtual = 0.0
+        if resume_from is not None:
+            from repro.engine import resilience as _rez
+            global_params, key, t_virtual, server_version = (
+                _rez.restore_async(resume_from, runner, clients, log,
+                                   injector, global_params, heap, pending))
+            if checkpoint is not None:
+                checkpoint.mark(sum(log.update_counts.values()))
+        else:
+            # startup schedule: one compiled scan for the N-wide PRNG
+            # chain (bitwise the old per-client split loop),
+            # O(1)-per-client dispatches (the batch permutations
+            # materialize lazily at staging), and a single O(N) heapify
+            # instead of N pushes — pop-order-identical since every
+            # (duration, cid) is distinct
+            key, subs = split_key_chain(key, len(clients))
+            entries = []
+            for c, sub in zip(clients, subs):
+                plan = runner.dispatch(c, global_params, sub, server_version)
+                pending[c.cid] = plan
+                entries.append((plan.duration, c.cid))
+            heap.extend(entries)
+            heapq.heapify(heap)
+            # tiered store: warm the hot set for the first cohorts
+            # (restore skips this — the snapshot already reflects it)
+            runner.prefetch_upcoming(heap, pending)
 
     done = False
     # pipelined submit/drain: cohorts in flight are capped at
@@ -1269,165 +1316,179 @@ def run_async_engine(
     # cohorts while the host waits).  Serial runs (depth 1) never enter
     # the queue: their donation-chained submits already block per cohort.
     inflight = deque()
+    n_cohort = 0
     while heap and not done:
-        events = pop_cohort(heap, cfg.staleness_window, cfg.max_cohort,
-                            bucket_pow2=cfg.pow2_cohorts)
-        plans = []
-        if injector is None:
-            for t, cid in events:
-                p = pending.pop(cid)
-                p.t_complete = t
-                plans.append(p)
-        else:
-            # every popped completion is a delivery ATTEMPT the injector
-            # resolves: duplicates are deduped, retried/late uploads
-            # re-enter the heap at a later virtual time (the pending plan
-            # stays pending), lost updates consume their plan as a
-            # zero-weight mask member (dropped=True)
-            for t, cid in events:
-                verdict, aux = injector.on_completion(cid, t)
-                if verdict == "duplicate":
-                    continue
-                if verdict == "requeue":
-                    heapq.heappush(heap, (aux, cid))
-                    continue
-                p = pending.pop(cid)
-                p.t_complete = t
-                if verdict == "drop":
-                    p.dropped = True
+        with spans.step_span("fl.cohort", n_cohort):
+            n_cohort += 1
+            with spans.span("fl.pop"):
+                events = pop_cohort(heap, cfg.staleness_window,
+                                    cfg.max_cohort,
+                                    bucket_pow2=cfg.pow2_cohorts)
+                plans = []
+                if injector is None:
+                    for t, cid in events:
+                        p = pending.pop(cid)
+                        p.t_complete = t
+                        plans.append(p)
                 else:
-                    p.corrupt_scale = injector.take_corruption(cid)
-                    if aux is not None:     # deliver + a scheduled dup copy
-                        heapq.heappush(heap, (aux, cid))
-                plans.append(p)
+                    # every popped completion is a delivery ATTEMPT the
+                    # injector resolves: duplicates are deduped,
+                    # retried/late uploads re-enter the heap at a later
+                    # virtual time (the pending plan stays pending), lost
+                    # updates consume their plan as a zero-weight mask
+                    # member (dropped=True)
+                    for t, cid in events:
+                        verdict, aux = injector.on_completion(cid, t)
+                        if verdict == "duplicate":
+                            continue
+                        if verdict == "requeue":
+                            heapq.heappush(heap, (aux, cid))
+                            continue
+                        p = pending.pop(cid)
+                        p.t_complete = t
+                        if verdict == "drop":
+                            p.dropped = True
+                        else:
+                            p.corrupt_scale = injector.take_corruption(cid)
+                            if aux is not None:  # deliver + a dup copy
+                                heapq.heappush(heap, (aux, cid))
+                        plans.append(p)
             if not plans:                   # the whole pop was ghosts/retries
                 continue
-        t_virtual = plans[-1].t_complete
-        new_stacked = runner.submit_cohort(runner.stage_cohort(plans))
-        screen_handle = runner.take_screen_handle()
-        log.cohort_sizes.append(len(plans))
-        n_dropped = sum(1 for p in plans if p.dropped)
-        if n_dropped:
-            injector.note_degraded()
-        if screener is not None:
-            # screen every DELIVERED member at its completion time (one
-            # fetch per cohort — the screen_verdict_syncs bucket); a
-            # reject becomes a zero-coefficient mask slot exactly like a
-            # lost update, so the merge below re-uses the same program
-            fin, nrm = runner.fetch_screen(screen_handle, len(plans))
-            for j, p in enumerate(plans):
-                if not p.dropped and not screener.screen(
-                        p.cid, p.t_complete, fin[j], nrm[j]):
-                    p.dropped = True
+            t_virtual = plans[-1].t_complete
+            new_stacked = runner.submit_cohort(runner.stage_cohort(plans))
+            screen_handle = runner.take_screen_handle()
+            log.cohort_sizes.append(len(plans))
             n_dropped = sum(1 for p in plans if p.dropped)
+            if n_dropped:
+                injector.note_degraded()
+            if screener is not None:
+                # screen every DELIVERED member at its completion time
+                # (one fetch per cohort — the screen_verdict_syncs
+                # bucket); a reject becomes a zero-coefficient mask slot
+                # exactly like a lost update, so the merge below re-uses
+                # the same program
+                with spans.span("fl.screen"):
+                    fin, nrm = runner.fetch_screen(screen_handle, len(plans))
+                    for j, p in enumerate(plans):
+                        if not p.dropped and not screener.screen(
+                                p.cid, p.t_complete, fin[j], nrm[j]):
+                            p.dropped = True
+                    n_dropped = sum(1 for p in plans if p.dropped)
 
-        if _fused_ok(strategy, clients, plans, cfg):
-            # staleness weights alpha/(1+tau_i), folded so the single
-            # weights-vector reduction equals the sequential merges; member
-            # i's tau accounts for the i earlier DELIVERED merges in this
-            # cohort (dropped members merge with weight 0 — the fold gives
-            # them coefficient exactly 0 and leaves the survivors' terms
-            # bit-identical to a cohort they were never part of)
-            taus, weights = [], []
-            n_del = 0
-            for p in plans:
-                if p.dropped:
-                    taus.append(0)
-                    weights.append(0.0)
+            with spans.span("fl.merge"):
+                if _fused_ok(strategy, clients, plans, cfg):
+                    # staleness weights alpha/(1+tau_i), folded so the
+                    # single weights-vector reduction equals the
+                    # sequential merges; member i's tau accounts for the i
+                    # earlier DELIVERED merges in this cohort (dropped
+                    # members merge with weight 0 — the fold gives them
+                    # coefficient exactly 0 and leaves the survivors'
+                    # terms bit-identical to a cohort they were never
+                    # part of)
+                    taus, weights = [], []
+                    n_del = 0
+                    for p in plans:
+                        if p.dropped:
+                            taus.append(0)
+                            weights.append(0.0)
+                        else:
+                            tau = (server_version + n_del) - p.model_version
+                            taus.append(tau)
+                            weights.append(strategy.mixing_weight(tau))
+                            n_del += 1
+                    g_coeff, coeffs = fold_cohort_weights(weights)
+                    global_params = runner.merge_cohort(
+                        global_params, new_stacked,
+                        _pad_coeffs(coeffs, new_stacked), g_coeff)
+                    server_version += n_del
                 else:
-                    tau = (server_version + n_del) - p.model_version
-                    taus.append(tau)
-                    weights.append(strategy.mixing_weight(tau))
-                    n_del += 1
-            g_coeff, coeffs = fold_cohort_weights(weights)
-            global_params = runner.merge_cohort(
-                global_params, new_stacked, _pad_coeffs(coeffs, new_stacked),
-                g_coeff)
-            server_version += n_del
-        else:
-            taus, weights = [], []
-            for i, p in enumerate(plans):
-                if p.dropped:
-                    taus.append(0)
-                    weights.append(0.0)
-                    continue
-                up = runner.upload(p, unstack_tree(new_stacked, i))
-                tau = server_version - p.model_version
-                global_params, inc, w = apply_update(
-                    strategy, global_params, up, tau, eps_spent=p.epsilon)
-                server_version += inc
-                taus.append(tau)
-                weights.append(w)
+                    taus, weights = [], []
+                    for i, p in enumerate(plans):
+                        if p.dropped:
+                            taus.append(0)
+                            weights.append(0.0)
+                            continue
+                        up = runner.upload(p, unstack_tree(new_stacked, i))
+                        tau = server_version - p.model_version
+                        global_params, inc, w = apply_update(
+                            strategy, global_params, up, tau,
+                            eps_spent=p.epsilon)
+                        server_version += inc
+                        taus.append(tau)
+                        weights.append(w)
 
-        for p, tau, w in zip(plans, taus, weights):
-            if p.dropped:
-                continue
-            c = clients[p.cid]
-            log.staleness[c.tier].append(tau)
-            log.update_counts[c.tier] += 1
-            log.eps_trajectory[c.tier].append(p.epsilon)
-            log.influence[c.tier] += float(w)
-
-        total_updates = sum(log.update_counts.values())
-        crossed = any((total_updates - j) % eval_every == 0
-                      for j in range(len(plans) - n_dropped))
-        if crossed:
-            # eval boundary — the pipelined schedule's ONLY sanctioned
-            # host block between start and end of run: fetching the
-            # global accuracy synchronizes every older cohort too
-            runner.eval_boundary(True)
-            acc = _host_fetch(runner, accuracy_fn(global_params, test_data))
-            log.times.append(t_virtual)
-            log.global_acc.append(acc)
-            log.server_version.append(server_version)
-            eval_all(clients, global_params, accuracy_fn, log)
-            runner.eval_boundary(False)
-            inflight.clear()
-            if target_acc is not None and acc >= target_acc:
+            with spans.span("fl.books"):
+                for p, tau, w in zip(plans, taus, weights):
+                    if p.dropped:
+                        continue
+                    c = clients[p.cid]
+                    log.staleness[c.tier].append(tau)
+                    log.update_counts[c.tier] += 1
+                    log.eps_trajectory[c.tier].append(p.epsilon)
+                    log.influence[c.tier] += float(w)
+                total_updates = sum(log.update_counts.values())
+                crossed = any((total_updates - j) % eval_every == 0
+                              for j in range(len(plans) - n_dropped))
+            if crossed:
+                # eval boundary — the pipelined schedule's ONLY sanctioned
+                # host block between start and end of run: fetching the
+                # global accuracy synchronizes every older cohort too
+                with spans.span("fl.eval"):
+                    runner.eval_boundary(True)
+                    acc = _host_fetch(
+                        runner, accuracy_fn(global_params, test_data))
+                    log.times.append(t_virtual)
+                    log.global_acc.append(acc)
+                    log.server_version.append(server_version)
+                    eval_all(clients, global_params, accuracy_fn, log)
+                    runner.eval_boundary(False)
+                inflight.clear()
+                if target_acc is not None and acc >= target_acc:
+                    done = True
+            if total_updates >= max_updates or (
+                    max_time and t_virtual >= max_time):
                 done = True
-        if total_updates >= max_updates or (max_time and t_virtual >= max_time):
-            done = True
 
-        if not done:
-            for p in plans:
-                c = clients[p.cid]
-                # joint aggregation-privacy adaptation: a client that has
-                # exhausted its budget STOPS training (see legacy loop) —
-                # dropped members DO re-dispatch (their device crashed at
-                # upload, the budget was still spent)
-                if (isinstance(strategy, AdaptiveAsync)
-                        and p.epsilon >= strategy.eps_target):
-                    continue
-                key, sub = jax.random.split(key)
-                plan = runner.dispatch(c, global_params, sub, server_version)
-                pending[c.cid] = plan
-                t_next = p.t_complete + plan.duration
-                if injector is not None:
-                    # leave/rejoin churn delays the next local round
-                    t_next += injector.redispatch_delay(c.cid, p.t_complete)
-                heapq.heappush(heap, (t_next, c.cid))
-            # tiered store: stage the heap head's members while the
-            # submitted cohort executes (O(lookahead * log N) peek)
-            runner.prefetch_upcoming(heap, pending)
-            if runner.pipelined:
-                inflight.append(jax.tree_util.tree_leaves(new_stacked)
-                                + jax.tree_util.tree_leaves(global_params))
-                while len(inflight) > cfg.pipeline_depth:
-                    runner.drain_waits += 1
-                    jax.block_until_ready(inflight.popleft())
-            if checkpoint is not None and checkpoint.due(total_updates):
-                from repro.engine import resilience as _rez
-                _rez.save_async(checkpoint, runner, clients, log, injector,
-                                global_params, key, heap, pending, t_virtual,
-                                server_version, total_updates)
+            if not done:
+                with spans.span("fl.dispatch"):
+                    for p in plans:
+                        c = clients[p.cid]
+                        # joint aggregation-privacy adaptation: a client
+                        # that has exhausted its budget STOPS training
+                        # (see legacy loop) — dropped members DO
+                        # re-dispatch (their device crashed at upload,
+                        # the budget was still spent)
+                        if (isinstance(strategy, AdaptiveAsync)
+                                and p.epsilon >= strategy.eps_target):
+                            continue
+                        key, sub = jax.random.split(key)
+                        plan = runner.dispatch(c, global_params, sub,
+                                               server_version)
+                        pending[c.cid] = plan
+                        t_next = p.t_complete + plan.duration
+                        if injector is not None:
+                            # leave/rejoin churn delays the next round
+                            t_next += injector.redispatch_delay(
+                                c.cid, p.t_complete)
+                        heapq.heappush(heap, (t_next, c.cid))
+                # tiered store: stage the heap head's members while the
+                # submitted cohort executes (O(lookahead * log N) peek)
+                runner.prefetch_upcoming(heap, pending)
+                if runner.pipelined:
+                    inflight.append(jax.tree_util.tree_leaves(new_stacked)
+                                    + jax.tree_util.tree_leaves(global_params))
+                    while len(inflight) > cfg.pipeline_depth:
+                        with spans.span("fl.drain"):
+                            runner.drain_waits += 1
+                            jax.block_until_ready(inflight.popleft())
+                if checkpoint is not None and checkpoint.due(total_updates):
+                    from repro.engine import resilience as _rez
+                    with spans.span("fl.checkpoint"):
+                        _rez.save_async(checkpoint, runner, clients, log,
+                                        injector, global_params, key, heap,
+                                        pending, t_virtual, server_version,
+                                        total_updates)
 
-    for c in clients:
-        log.resources[c.tier] = c.clock.resource_sample()
-        log.dropouts[c.tier] = c.clock.dropouts
-    if injector is not None or screener is not None:
-        ev = list(injector.events) if injector is not None else []
-        if screener is not None:
-            ev += list(screener.events)
-        log.fault_events = ev
-    log.engine_stats = validate_engine_stats(runner.stats())
+    _finish(runner, clients, log, injector, screener)
     return global_params, log

@@ -45,6 +45,7 @@ from typing import Optional
 import jax
 import numpy as np
 
+from repro.core import spans
 from repro.core.runlog import STORE_STATS_KEYS
 
 
@@ -200,30 +201,32 @@ class TieredStateStore:
         classifying hits by whether the prefetcher staged them.  The
         whole cohort is pinned while slots are grabbed — a cohort larger
         than the hot set is a config error surfaced as a deadlock."""
-        keep = set(cids)
-        loads, slots = [], []
-        for cid in cids:
-            self.tick += 1
-            self.fetches += 1
-            slot = self.slot_of.get(cid)
-            if slot is not None:
-                if cid in self.prefetched:
-                    self.prefetched.discard(cid)
-                    self.prefetch_hits += 1
-                    # the prefetch already queued this cid's params write
+        with spans.span("fl.store.acquire"):
+            keep = set(cids)
+            loads, slots = [], []
+            for cid in cids:
+                self.tick += 1
+                self.fetches += 1
+                slot = self.slot_of.get(cid)
+                if slot is not None:
+                    if cid in self.prefetched:
+                        self.prefetched.discard(cid)
+                        self.prefetch_hits += 1
+                        # the prefetch already queued this cid's params write
+                    else:
+                        self.hot_hits += 1
+                        self.runner._queue_write(
+                            slot, self.pending_params[cid])
                 else:
-                    self.hot_hits += 1
+                    self.stall_waits += 1
+                    slot = self._grab_slot(keep)
+                    self._assign(cid, slot)
                     self.runner._queue_write(slot, self.pending_params[cid])
-            else:
-                self.stall_waits += 1
-                slot = self._grab_slot(keep)
-                self._assign(cid, slot)
-                self.runner._queue_write(slot, self.pending_params[cid])
-                loads.append((cid, slot))
-            self.seq[cid] = self.tick
-            slots.append(slot)
-        self._load_slots(loads)
-        return slots
+                    loads.append((cid, slot))
+                self.seq[cid] = self.tick
+                slots.append(slot)
+            self._load_slots(loads)
+            return slots
 
     def prefetch_cids(self, cids):
         """Stage upcoming members' slots ahead of their cohort.  Callers
@@ -294,7 +297,8 @@ class TieredStateStore:
         self.evictions += 1
         if cid in self.dirty:
             self.dirty.discard(cid)
-            row = self.runner.spill_opt_slot(slot)
+            with spans.span("fl.store.spill"):
+                row = self.runner.spill_opt_slot(slot)
             self.cold[cid] = row
             self.spill_bytes += _tree_nbytes(row)
         return slot
@@ -307,16 +311,17 @@ class TieredStateStore:
         would hold)."""
         if not loads:
             return
-        cold_pairs = [(c, s) for c, s in loads if c in self.cold]
-        fresh_pairs = [(c, s) for c, s in loads if c not in self.cold]
-        if fresh_pairs:
-            self.runner.init_opt_rows(
-                self.pending_params[fresh_pairs[0][0]],
-                [s for _, s in fresh_pairs])
-        if cold_pairs:
-            self.runner.load_opt_rows(
-                [self.cold[c] for c, _ in cold_pairs],
-                [s for _, s in cold_pairs])
+        with spans.span("fl.store.load"):
+            cold_pairs = [(c, s) for c, s in loads if c in self.cold]
+            fresh_pairs = [(c, s) for c, s in loads if c not in self.cold]
+            if fresh_pairs:
+                self.runner.init_opt_rows(
+                    self.pending_params[fresh_pairs[0][0]],
+                    [s for _, s in fresh_pairs])
+            if cold_pairs:
+                self.runner.load_opt_rows(
+                    [self.cold[c] for c, _ in cold_pairs],
+                    [s for _, s in cold_pairs])
 
     # -- stats / checkpoint state -----------------------------------------
     def stats(self) -> dict:

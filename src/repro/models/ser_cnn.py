@@ -130,11 +130,11 @@ def loss_fn(params, example, cfg: SERConfig = SERConfig()):
 
 def make_accuracy_fn(cfg: SERConfig = SERConfig(), batch: int = 512):
     @jax.jit
-    def _acc(params, data):
+    def eval_accuracy(params, data):
         logits = jax.vmap(lambda x: apply(params, x, cfg))(data["x"])
         return jnp.mean((jnp.argmax(logits, -1) == data["y"]).astype(jnp.float32))
 
-    return _acc
+    return eval_accuracy
 
 
 def param_count(params) -> int:

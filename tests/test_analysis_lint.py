@@ -31,6 +31,7 @@ def _codes(findings):
     ("rep004", ["REP004"]),
     ("rep005", ["REP005", "REP005", "REP005"]),
     ("rep006", ["REP006", "REP006", "REP006"]),
+    ("rep007", ["REP007", "REP007", "REP007"]),
 ])
 def test_seeded_violation_fires(code, expected):
     findings = lint.run([_fixture(f"{code}_bad.py")])
@@ -41,10 +42,20 @@ def test_seeded_violation_fires(code, expected):
 
 
 @pytest.mark.parametrize(
-    "code", ["rep001", "rep002", "rep003", "rep004", "rep005", "rep006"])
+    "code",
+    ["rep001", "rep002", "rep003", "rep004", "rep005", "rep006", "rep007"])
 def test_clean_twin_passes(code):
     findings = lint.run([_fixture(f"{code}_clean.py")])
     assert findings == [], [f.format() for f in findings]
+
+
+def test_unregistered_span_name_fires_beside_the_registry():
+    # linted alone the fixture has no registry to check names against;
+    # beside repro/core/spans.py its unregistered "fl.submitt" fires too
+    registry = os.path.join(SRC, "repro", "core", "spans.py")
+    findings = lint.run([_fixture("rep007_bad.py"), registry])
+    assert _codes(findings) == ["REP007"] * 4, [f.format() for f in findings]
+    assert all(f.path.endswith("rep007_bad.py") for f in findings)
 
 
 def test_bare_suppression_is_rep000_and_does_not_suppress():
