@@ -67,16 +67,55 @@ def init(key: jax.Array, cfg: SERConfig = SERConfig()):
     }
 
 
-def _conv1d(x, p):
-    """x: (T, Cin) -> (T, Cout), SAME padding."""
-    y = jax.lax.conv_general_dilated(
+def _conv_same(x, w):
+    """x: (T, Cin), w: (K, Cin, Cout) -> (T, Cout), SAME padding."""
+    return jax.lax.conv_general_dilated(
         x[None],                       # (1, T, Cin)
-        p["w"],                        # (K, Cin, Cout)
+        w,                             # (K, Cin, Cout)
         window_strides=(1,),
         padding="SAME",
         dimension_numbers=("NWC", "WIO", "NWC"),
     )[0]
-    return y + p["b"]
+
+
+@jax.custom_vjp
+def _conv(x, w):
+    return _conv_same(x, w)
+
+
+def _conv_fwd(x, w):
+    return _conv_same(x, w), (x, w)
+
+
+def _conv_bwd(res, dy):
+    """Input gradient: the conv's own transpose.  Weight gradient: the
+    im2col contraction dW[k, c, o] = sum_t x_pad[t + k, c] dy[t, o].
+
+    Autodiff's weight gradient is a convolution too, and under the
+    per-example ``vmap(grad)`` of DP-SGD JAX batches it by folding the
+    batch into the feature dimension: a grouped convolution
+    (``feature_group_count`` = B), which the TPU compiler runs as a VPU
+    multiply-reduce.  Written as a contraction it batches into one
+    ``dot_general`` over the batch on the MXU.  HIGHEST keeps f32
+    products, as the multiply-reduce had."""
+    x, w = res
+    _, conv_vjp = jax.vjp(lambda x: _conv_same(x, w), x)
+    (dx,) = conv_vjp(dy)
+    t, k = x.shape[0], w.shape[0]
+    lo = (k - 1) // 2                  # SAME: the odd pad frame goes last
+    xp = jnp.pad(x, ((lo, k - 1 - lo), (0, 0)))
+    patches = jnp.stack([xp[i:i + t] for i in range(k)], axis=1)  # (T,K,Cin)
+    dw = jnp.einsum("tkc,to->kco", patches, dy,
+                    precision=jax.lax.Precision.HIGHEST)
+    return dx, dw
+
+
+_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def _conv1d(x, p):
+    """x: (T, Cin) -> (T, Cout), SAME padding."""
+    return _conv(x, p["w"]) + p["b"]
 
 
 def _group_norm(x, p, groups):
