@@ -72,7 +72,7 @@ def main(argv=None):
         print(json.dumps(row), flush=True)
 
     seeds = [args.first_seed + 7919 * i for i in range(args.seeds)]
-    corpus = reference.Corpus(cell.config)
+    corpus = cell.family.Corpus(cell.config)
 
     sigmas = cell.traffic["sigmas"]
     for i, seed in enumerate(seeds):
@@ -88,7 +88,8 @@ def main(argv=None):
         del session
         t1 = time.perf_counter()
         init, ref, ref_books = reference.simulate(
-            cell.config, cell.traffic, corpus, tb_seed, sigma)
+            cell.family, cell.config, cell.traffic, corpus, tb_seed,
+            sigma)
         t2 = time.perf_counter()
         emit({"kind": "program", "seed": seed, "sigma": sigma,
               "program_s": t1 - t0, "reference_s": t2 - t1,
@@ -96,15 +97,15 @@ def main(argv=None):
               "leaves": correct.leaf_readings(init, params, ref)})
         if i < args.controls:
             _, ctl, ctl_books = reference.simulate(
-                cell.config, cell.traffic, corpus, tb_seed, sigma,
-                dtype=jnp.bfloat16)
+                cell.family, cell.config, cell.traffic, corpus, tb_seed,
+                sigma, dtype=jnp.bfloat16)
             emit({"kind": "control", "seed": seed, "sigma": sigma,
                   **correct.compare(init, ctl, ctl_books, ref, ref_books)})
         if i < fault_seeds:
             for fault in faults:
                 _, bad, bad_books = reference.simulate(
-                    cell.config, cell.traffic, corpus, tb_seed, sigma,
-                    fault=fault)
+                    cell.family, cell.config, cell.traffic, corpus,
+                    tb_seed, sigma, fault=fault)
                 emit({"kind": f"fault:{fault}", "seed": seed, "sigma": sigma,
                       **correct.compare(init, bad, bad_books, ref, ref_books),
                       "leaves": correct.leaf_readings(init, bad, ref)})

@@ -2,9 +2,10 @@
 the chip could take for every call in the window, over the kernel's
 device time in the trace.  The kernel is bound by memory (it reads the
 (K*B, D) float32 per-example gradients twice at 0.5 operations per
-byte), so the least time is its bytes (``bench/flops.py``) over the HBM
-bandwidth.  Every cohort of K members runs the padded step count, each
-step one call over all K members."""
+byte), so the least time is its bytes (``bench/flops.py``, over the
+family's parameter count) over the HBM bandwidth.  Every cohort of K
+members runs the padded step count, each step one call over all K
+members."""
 from bench import flops
 
 
@@ -15,7 +16,7 @@ def read(run):
     spent = t.kernel_seconds()
     if spent <= 0:
         return None
-    d = flops.param_count(cfg["model"])
+    d = run.cell.family.param_count(cfg["model"])
     b = cfg["testbed"]["batch_size"]
     least = 0.0
     for k in run.window.cohort_sizes:

@@ -1,15 +1,15 @@
 """Plain reference of one FedAsync experiment, written from the semantics
 and importing nothing of the program under test.
 
-It rebuilds, from the configuration and the seed alone:
+The model family (``bench/families/<family>.py``, named by the
+configuration) supplies the corpus, the model's initial weights and its
+per-example loss.  From the configuration and the seed alone this module
+rebuilds the rest:
 
-* the synthetic SER corpus (shared low-rank class basis, speaker fields,
-  Gaussian noise, label flips), its class-balanced iid split over the
-  clients and each client's 80/20 train/test split;
+* the corpus's class-balanced iid split over the clients and each
+  client's 80/20 train/test split;
 * each client's tier clock (lognormal compute time, exchange latency,
   dropout penalty) and its minibatch permutations;
-* the SER 1D-CNN (two conv+GroupNorm+ReLU+maxpool blocks, FC, output)
-  and its initial weights;
 * per-example DP-SGD: per-example gradients, clipping of each to C over
   all leaves, the batch mean, Gaussian noise of stddev sigma*C/B drawn
   leaf by leaf, then Adam, with every client keeping its Adam state
@@ -28,6 +28,7 @@ that dtype: ``jnp.bfloat16`` is the lower-precision control.
 from __future__ import annotations
 
 import heapq
+import json
 import math
 
 import jax
@@ -49,41 +50,8 @@ DELTA = 1e-5
 
 
 # ---------------------------------------------------------------------------
-# corpus and split
+# split
 # ---------------------------------------------------------------------------
-
-def _smooth(rng, n, length, smooth=6):
-    z = rng.standard_normal((n, length + smooth))
-    k = np.ones(smooth) / smooth
-    out = np.stack([np.convolve(z[i], k, mode="valid")[:length]
-                    for i in range(n)])
-    return out / (out.std(axis=1, keepdims=True) + 1e-8)
-
-
-def make_corpus(d: dict):
-    """(x (N, T, M) float32, y (N,) int32) from the corpus parameters."""
-    rng = np.random.default_rng(d["seed"])
-    T, M, R = d["time_frames"], d["n_mels"], d["rank"]
-    u, v = _smooth(rng, R, T), _smooth(rng, R, M)
-    cls = rng.standard_normal((d["n_classes"], R)) * d["class_gain"]
-    ns, sr = d["n_speakers"], d["speaker_rank"]
-    su = _smooth(rng, ns * sr, T).reshape(ns, sr, T)
-    sv = _smooth(rng, ns * sr, M).reshape(ns, sr, M)
-    sb = rng.standard_normal((ns, sr)) * d["speaker_gain"]
-    n = d["n_total"]
-    y_true = rng.integers(0, d["n_classes"], size=n)
-    spk = rng.integers(0, ns, size=n)
-    coeffs = cls[y_true] + d["coeff_jitter"] * rng.standard_normal((n, R))
-    x = np.einsum("nr,rt,rm->ntm", coeffs, u, v)
-    x += np.einsum("ns,nst,nsm->ntm", sb[spk], su[spk], sv[spk])
-    x += d["noise"] * rng.standard_normal((n, T, M))
-    x = (x - x.mean()) / (x.std() + 1e-8)
-    y = y_true.copy()
-    if d["label_noise"] > 0:
-        flip = rng.random(n) < d["label_noise"]
-        y[flip] = rng.integers(0, d["n_classes"], size=int(flip.sum()))
-    return x.astype(np.float32), y.astype(np.int32)
-
 
 def client_rows(y, num_clients: int, seed: int):
     """Per client, (train rows, test rows) as indices into the corpus:
@@ -104,78 +72,28 @@ def client_rows(y, num_clients: int, seed: int):
 
 
 # ---------------------------------------------------------------------------
-# the SER CNN
-# ---------------------------------------------------------------------------
-
-def init_params(key, m: dict):
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-
-    def uni(k, shape, fan_in):
-        s = 1.0 / jnp.sqrt(fan_in)
-        return jax.random.uniform(k, shape, jnp.float32, -s, s)
-
-    c1, c2, ks, nm = m["channels1"], m["channels2"], m["kernel"], m["n_mels"]
-    flat = (m["time_frames"] // 4) * c2
-    return {
-        "conv1": {"w": uni(k1, (ks, nm, c1), nm * ks), "b": jnp.zeros((c1,))},
-        "gn1": {"scale": jnp.ones((c1,)), "bias": jnp.zeros((c1,))},
-        "conv2": {"w": uni(k2, (ks, c1, c2), c1 * ks), "b": jnp.zeros((c2,))},
-        "gn2": {"scale": jnp.ones((c2,)), "bias": jnp.zeros((c2,))},
-        "fc1": {"w": uni(k3, (flat, m["fc_dim"]), flat),
-                "b": jnp.zeros((m["fc_dim"],))},
-        "out": {"w": uni(k4, (m["fc_dim"], m["num_classes"]), m["fc_dim"]),
-                "b": jnp.zeros((m["num_classes"],))},
-    }
-
-
-def logits(p, x, groups: int):
-    """x: (T, n_mels) -> class logits; convs over time, SAME padding."""
-    def conv(h, q):
-        return jax.lax.conv_general_dilated(
-            h[None], q["w"], (1,), "SAME",
-            dimension_numbers=("NWC", "WIO", "NWC"))[0] + q["b"]
-
-    def gnorm(h, q):
-        t, c = h.shape
-        g = h.reshape(t, groups, c // groups)
-        mu = g.mean(axis=(0, 2), keepdims=True)
-        var = g.var(axis=(0, 2), keepdims=True)
-        g = (g - mu) * jax.lax.rsqrt(var + 1e-5)
-        return g.reshape(t, c) * q["scale"] + q["bias"]
-
-    def pool(h):
-        t, c = h.shape
-        return h.reshape(t // 2, 2, c).max(axis=1)
-
-    h = pool(jax.nn.relu(gnorm(conv(x, p["conv1"]), p["gn1"])))
-    h = pool(jax.nn.relu(gnorm(conv(h, p["conv2"]), p["gn2"])))
-    h = jax.nn.relu(h.reshape(-1) @ p["fc1"]["w"] + p["fc1"]["b"])
-    return h @ p["out"]["w"] + p["out"]["b"]
-
-
-# ---------------------------------------------------------------------------
 # one client's local round of per-example DP-SGD with Adam
 # ---------------------------------------------------------------------------
 
 _ROUNDS = {}
 
 
-def round_fn(n_steps: int, m: dict, clip: float, lr: float, dtype,
+def round_fn(family, n_steps: int, m: dict, clip: float, lr: float, dtype,
              precision: str, half_batch: bool = False):
-    """Jitted local round: (params, adam, xs (S,B,T,M), ys (S,B), key,
-    stddev) -> (params, adam).  Built once per step count and dtype.
-    ``half_batch`` plants a fault: each step leaves out the second half
-    of its batch and takes the mean over the rest."""
-    key = (n_steps, tuple(sorted(m.items())), clip, lr, jnp.dtype(dtype).name,
-           precision, half_batch)
+    """Jitted local round of the family's model: (params, adam, xs (S,B,...),
+    ys (S,B), key, stddev) -> (params, adam).  Built once per family,
+    model, step count and dtype.  ``half_batch`` plants a fault: each
+    step leaves out the second half of its batch and takes the mean over
+    the rest."""
+    key = (family.__name__, n_steps, json.dumps(m, sort_keys=True), clip, lr,
+           jnp.dtype(dtype).name, precision, half_batch)
     if key in _ROUNDS:
         return _ROUNDS[key]
-    groups = m["gn_groups"]
     b1, b2, eps = 0.9, 0.999, 1e-8
     one = jnp.asarray(1, dtype)
 
     def loss(p, x, y):
-        return -jax.nn.log_softmax(logits(p, x.astype(dtype), groups))[y]
+        return family.loss(p, x.astype(dtype), y, m)
 
     def step(p, adam, x, y, sub, stddev):
         if half_batch:
@@ -264,17 +182,6 @@ def epsilon(q: float, sigma: float, steps: int) -> float:
 # the experiment
 # ---------------------------------------------------------------------------
 
-class Corpus:
-    """The corpus, on the device and (labels) on the host: built once per
-    configuration and shared by every seed, the reference and control."""
-
-    def __init__(self, cfg: dict):
-        x, y = make_corpus(cfg["data"])
-        self.labels = y
-        self.x = jnp.asarray(x)
-        self.y = jnp.asarray(y)
-
-
 def _pop(heap, window, max_size):
     events = [heapq.heappop(heap)]
     while heap and len(events) < max_size and heap[0][0] <= events[0][0] + window:
@@ -286,10 +193,11 @@ def _pop(heap, window, max_size):
     return events[:keep]
 
 
-def simulate(cfg: dict, traffic: dict, corpus: Corpus, seed: int,
+def simulate(family, cfg: dict, traffic: dict, corpus, seed: int,
              sigma: float, *, dtype=jnp.float32, precision: str | None = None,
              fault: str | None = None):
-    """One FedAsync experiment; returns ``(init_params, final_params,
+    """One FedAsync experiment of the family's model over ``corpus``
+    (``family.Corpus(cfg)``); returns ``(init_params, final_params,
     books)``.  ``fault`` plants one fault in place of the program's:
     ``"unchanged"`` (every local round returns its params unchanged),
     ``"half_batch"`` (each DP step uses half its batch) or ``"answer"``
@@ -304,7 +212,7 @@ def simulate(cfg: dict, traffic: dict, corpus: Corpus, seed: int,
     stddev = jnp.float32(sigma * clip / bsz)
     rows_of = client_rows(corpus.labels, n_clients, seed)
 
-    p0 = init_params(jax.random.PRNGKey(seed), m)
+    p0 = family.init_params(jax.random.PRNGKey(seed), m)
     glob = jax.tree_util.tree_map(lambda a: a.astype(dtype), p0)
     zeros = jax.tree_util.tree_map(jnp.zeros_like, glob)
     clocks, batch_rng, n_train, steps, adam = [], [], [], [], []
@@ -363,7 +271,7 @@ def simulate(cfg: dict, traffic: dict, corpus: Corpus, seed: int,
                 batch_rng[cid].permutation(n_train[cid])[: steps[cid] // epochs * bsz]
                 for _ in range(epochs)])
             rows = jnp.asarray(rows_of[cid][0][idx].reshape(steps[cid], bsz))
-            fn = round_fn(steps[cid], m, clip, lr, dtype, precision,
+            fn = round_fn(family, steps[cid], m, clip, lr, dtype, precision,
                           fault == "half_batch")
             new, adam[cid] = fn(p["params"], adam[cid], _gather(corpus.x, rows),
                                 _gather(corpus.y, rows), p["key"], stddev)

@@ -5,7 +5,10 @@
 
 The cell (``BENCHMARK.json`` -> ``workloads``) names a configuration,
 ``bench/configs/<config>.json``, and a traffic mix,
-``bench/traffic/<traffic>.json``.  The run:
+``bench/traffic/<traffic>.json``; the configuration names its model
+family, ``bench/families/<family>.py``, which maps it onto the
+program's ``TestbedConfig`` and supplies the reference's corpus and
+model and the readers' operation counts.  The run:
 
 1. builds the cell's ``ExperimentSpec``s (one per noise multiplier of
    the mix) and one ``Session``;
@@ -72,11 +75,32 @@ def load_json(path: Path) -> dict:
     return json.loads(path.read_text())
 
 
+def load_family(config_path: Path, config: dict, root: Path = ROOT):
+    """The model family module ``<root>/bench/families/<family>.py`` that
+    the configuration's ``family`` key names: ``testbed_fields(cfg)``,
+    ``Corpus(cfg)``, ``init_params(key, model)``, ``loss(params, x, y,
+    model)``, ``train_flops_per_example(model)``, ``param_count(model)``."""
+    families = root / "bench" / "families"
+    known = sorted(p.stem for p in families.glob("*.py"))
+    if "family" not in config:
+        raise SystemExit(f"{config_path}: no 'family' key; families: {known}")
+    name = config["family"]
+    if name not in known:
+        raise SystemExit(f"{config_path}: unknown family {name!r}; "
+                         f"families: {known}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_family_{name}", families / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 @dataclass
 class Cell:
     name: str
     chips: int
     config: dict
+    family: object      # the module named by the config: see ``load_family``
     traffic: dict
     end_to_end: list
     per_layer: list
@@ -94,8 +118,10 @@ class Cell:
         e2e = [m for m in bench["end_to_end"]
                if name in m.get("workloads", [name])]
         files = root / "bench"
-        return cls(name=name, chips=int(w["chips"]),
-                   config=load_json(files / "configs" / f"{w['config']}.json"),
+        config_path = files / "configs" / f"{w['config']}.json"
+        config = load_json(config_path)
+        return cls(name=name, chips=int(w["chips"]), config=config,
+                   family=load_family(config_path, config, root),
                    traffic=load_json(files / "traffic" / f"{w['traffic']}.json"),
                    end_to_end=e2e, per_layer=mine,
                    limits=load_json(files / "limits" / f"{name}.json"))
@@ -167,14 +193,12 @@ def build_specs(cell: Cell, seed: int):
     """``(window_specs, warmup_specs)`` of the program's API."""
     from repro.api import ExperimentSpec, RunBudget, StrategySpec
     from repro.core.testbed import TestbedConfig
-    from repro.data.synthetic_ser import SERDataConfig
     from repro.engine import EngineConfig, StoreConfig
-    from repro.models.ser_cnn import SERConfig
 
     c, t = cell.config, cell.traffic
     tb = TestbedConfig(
         **c["testbed"], seed=testbed_seed(seed), dp_path=t["dp_path"],
-        data=SERDataConfig(**c["data"]), model=SERConfig(**c["model"]))
+        **cell.family.testbed_fields(c))
     mesh = None
     if t["mesh"]:
         from repro.engine import cohort_mesh
@@ -284,9 +308,9 @@ def check_against_reference(cell: Cell, seed: int, window: Window) -> tuple:
     from bench import reference
     exp = random.Random(seed).choice(window.experiments)
     tb_seed = testbed_seed(seed)
-    corpus = reference.Corpus(cell.config)
+    corpus = cell.family.Corpus(cell.config)
     init, ref, books = reference.simulate(
-        cell.config, cell.traffic, corpus, tb_seed, exp["sigma"])
+        cell.family, cell.config, cell.traffic, corpus, tb_seed, exp["sigma"])
     numbers = correct.compare(init, exp["params"], exp["books"], ref, books)
     return correct.judge(numbers,
                          correct.limits_for(cell.limits, exp["sigma"]))

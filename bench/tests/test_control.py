@@ -18,17 +18,18 @@ def cell(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def ref(cell):
-    corpus = reference.Corpus(cell.config)
+    corpus = cell.family.Corpus(cell.config)
     init, params, books = reference.simulate(
-        cell.config, cell.traffic, corpus, run.testbed_seed(SEED), 1.0)
+        cell.family, cell.config, cell.traffic, corpus,
+        run.testbed_seed(SEED), 1.0)
     return corpus, init, params, books
 
 
 def test_the_bfloat16_control_fails(cell, ref):
     corpus, init, params, books = ref
     _, ctl, ctl_books = reference.simulate(
-        cell.config, cell.traffic, corpus, run.testbed_seed(SEED), 1.0,
-        dtype=jnp.bfloat16)
+        cell.family, cell.config, cell.traffic, corpus,
+        run.testbed_seed(SEED), 1.0, dtype=jnp.bfloat16)
     numbers = correct.compare(init, ctl, ctl_books, params, books)
     limits = correct.limits_for(cell.limits, 1.0)
     ok, checks = correct.judge(numbers, limits)

@@ -1,7 +1,8 @@
-"""A whole benchmark run of the tiny cell, with the look for a chip
-skipped and the timed path broken underneath: ``correct`` comes out
-false for each fault the cell can have.  (The cell runs on one chip, so
-there is no exchange between chips to leave out.)"""
+"""A whole benchmark run of each tiny cell (the SER CNN and the second
+model family, ``ser_linear``), with the look for a chip skipped and the
+timed path broken underneath: ``correct`` comes out false for each
+fault the cell can have.  (The cells run on one chip, so there is no
+exchange between chips to leave out.)"""
 import pytest
 
 from bench import run
@@ -15,8 +16,11 @@ def root(tmp_path_factory):
     return root
 
 
-def bench_run(root, seed):
-    return run.run(["--workload", "tiny", "--seed", str(seed),
+CELLS = list(tiny.CELLS)
+
+
+def bench_run(root, seed, cell):
+    return run.run(["--workload", cell, "--seed", str(seed),
                     "--seconds", "0.5", "--trace", "0"],
                    require_chip=False, root=root)
 
@@ -64,16 +68,18 @@ def answer(monkeypatch):
                         lambda self, c, s: eps(self, c, s) * (1 + 1e-6))
 
 
-def test_a_sound_run_is_correct(root, fresh_steps):
-    res = bench_run(root, 2 ** 31 + 11)
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_sound_run_is_correct(root, fresh_steps, cell):
+    res = bench_run(root, 2 ** 31 + 11, cell)
     assert res["correct"], res["checks"]
     assert res["attempted"] >= 1 and res["failed"] == 0
     assert list(res)[-1] == "checks"
 
 
+@pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("fault", [unchanged, half_batch, answer])
 def test_a_broken_timed_path_is_not_correct(root, fresh_steps, monkeypatch,
-                                            fault):
+                                            fault, cell):
     fault(monkeypatch)
-    res = bench_run(root, 2 ** 31 + 12)
+    res = bench_run(root, 2 ** 31 + 12, cell)
     assert not res["correct"], res["checks"]

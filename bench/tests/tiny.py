@@ -1,6 +1,10 @@
-"""A tiny cell for the CPU tests: the SER CNN at small widths over six
-clients, written as its own checkout root (``BENCHMARK.json`` plus
-``bench/configs``, ``bench/traffic`` and ``bench/limits``)."""
+"""Tiny cells for the CPU tests, written as their own checkout root
+(``BENCHMARK.json`` plus ``bench/configs``, ``bench/families``,
+``bench/traffic`` and ``bench/limits``): ``tiny``, the SER CNN at small
+widths over six clients, and ``tiny-linear``, the same clients and
+traffic with a second model family, ``ser_linear``: multinomial logistic
+regression over the same corpus (the program's ``ser_linear``
+workload), whose module the root holds beside a copy of ``ser_cnn``'s."""
 from __future__ import annotations
 
 import json
@@ -25,27 +29,70 @@ PARAM_LIMITS = {"tree_dist": 0.01, "change_gap": 0.01}
 LIMITS = {"limits": {"eps_gap": 1e-9, "books_diff": 0},
           "by_sigma": {repr(float(s)): PARAM_LIMITS
                        for s in TRAFFIC["sigmas"]}}
+# cell -> model family
+CELLS = {"tiny": "ser_cnn", "tiny-linear": "ser_linear"}
+
+SER_LINEAR = '''"""The ``ser_linear`` model family: multinomial logistic regression over
+the SER corpus, the program's ``ser_linear`` workload."""
+import jax
+import jax.numpy as jnp
+
+from bench.families.ser_cnn import Corpus  # noqa: F401  the same corpus
+
+
+def testbed_fields(cfg):
+    from repro.data.synthetic_ser import SERDataConfig
+    from repro.models.ser_cnn import SERConfig
+    return {"data": SERDataConfig(**cfg["data"]),
+            "model": SERConfig(**cfg["model"]), "workload": "ser_linear"}
+
+
+def init_params(key, m):
+    d = m["time_frames"] * m["n_mels"]
+    s = 1.0 / jnp.sqrt(d)
+    return {"w": jax.random.uniform(key, (d, m["num_classes"]), jnp.float32,
+                                    -s, s),
+            "b": jnp.zeros((m["num_classes"],), jnp.float32)}
+
+
+def loss(p, x, y, m):
+    return -jax.nn.log_softmax(x.reshape(-1) @ p["w"] + p["b"])[y]
+
+
+def train_flops_per_example(m):
+    return 3 * 2 * m["time_frames"] * m["n_mels"] * m["num_classes"]
+
+
+def param_count(m):
+    return (m["time_frames"] * m["n_mels"] + 1) * m["num_classes"]
+'''
 
 
 def write_root(root: Path, traffic: dict | None = None) -> str:
-    """Write the tiny cell under ``root``; returns its name."""
+    """Write the tiny cells under ``root``; returns the SER CNN's name."""
     name = "tiny"
-    for sub in ("configs", "traffic", "limits"):
+    for sub in ("configs", "families", "traffic", "limits"):
         (root / "bench" / sub).mkdir(parents=True, exist_ok=True)
     real = json.loads((REPO / "BENCHMARK.json").read_text())
-    per_layer = [{**m, "workloads": [name]} for m in real["per_layer"]]
+    per_layer = [{**m, "workloads": list(CELLS)} for m in real["per_layer"]]
     bench = {
         "end_to_end": real["end_to_end"],
         "per_layer": per_layer,
-        "workloads": [{"name": name, "config": name, "traffic": name,
-                       "chips": 1}],
+        "workloads": [{"name": cell, "config": cell, "traffic": name,
+                       "chips": 1} for cell in CELLS],
     }
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
-    (root / "bench" / "configs" / f"{name}.json").write_text(json.dumps(
-        {"model": MODEL, "data": DATA, "testbed": TESTBED,
-         "precision": "float32", "matmul_precision": "default"}))
+    families = root / "bench" / "families"
+    (families / "ser_cnn.py").write_text(
+        (REPO / "bench" / "families" / "ser_cnn.py").read_text())
+    (families / "ser_linear.py").write_text(SER_LINEAR)
+    for cell, family in CELLS.items():
+        (root / "bench" / "configs" / f"{cell}.json").write_text(json.dumps(
+            {"family": family, "model": MODEL, "data": DATA,
+             "testbed": TESTBED, "precision": "float32",
+             "matmul_precision": "default"}))
+        (root / "bench" / "limits" / f"{cell}.json").write_text(
+            json.dumps(LIMITS))
     (root / "bench" / "traffic" / f"{name}.json").write_text(
         json.dumps({**TRAFFIC, **(traffic or {})}))
-    (root / "bench" / "limits" / f"{name}.json").write_text(
-        json.dumps(LIMITS))
     return name
